@@ -276,4 +276,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

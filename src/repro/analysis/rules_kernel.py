@@ -92,6 +92,7 @@ def audit_vmem_budgets(vmem_limit: int = 0) -> List[Finding]:
                 head=head if b.fused_head else None,
                 tile_h=_AUDIT_TILE_H,
                 vmem_limit=limit,
+                streamed=b.streamed,
             )
             if safe < 1:
                 findings.append(

@@ -216,17 +216,17 @@ def miniconv_apply(params, spec: MiniConvSpec, x, *,
     call signature.
 
     ``interpret`` forces Pallas interpret (True) or compiled (False)
-    execution for the kernel tiers; ``None`` keeps the environment-derived
-    default (interpret off-TPU, compiled on TPU or with
-    ``REPRO_PALLAS_COMPILE=1``).
+    execution for the kernel tiers; ``None`` interprets on the CPU
+    backend and compiles on the chip
+    (``repro.kernels.interpret.resolve_interpret``).
 
     ``stream_chunk`` (fused tiers only) streams the micro-batch through
     VMEM in ``stream_chunk``-frame chunks
     (:func:`~repro.kernels.miniconv_pass.miniconv_encoder_stream`),
     lifting the batch-must-fit-VMEM cap.  ``use_kernel="fused+stream"``
     selects streaming with ``stream_chunk`` defaulting to the plan's
-    ``max_safe_batch``; batches within one chunk fall through to the plain
-    fused launch, so results are bitwise identical either way.
+    streamed ``max_safe_batch``; batches within one chunk fall through to
+    the plain fused launch, so results are bitwise identical either way.
     """
     from repro.core.backends import get_backend  # lazy: avoids cycle
     backend = get_backend(use_kernel)
@@ -248,8 +248,8 @@ def miniconv_apply(params, spec: MiniConvSpec, x, *,
         if backend.streamed and stream_chunk is None:
             hp = (plan.head(hw.shape[-1], activation=head_act)
                   if head is not None else None)
-            stream_chunk = max(1, plan.max_safe_batch(head=hp,
-                                                      tile_h=tile_h))
+            stream_chunk = max(1, plan.max_safe_batch(head=hp, tile_h=tile_h,
+                                                      streamed=True))
         if stream_chunk is not None:
             return miniconv_encoder_stream(
                 x, ws, bs, plan, chunk_b=stream_chunk, tile_h=tile_h,

@@ -76,15 +76,33 @@ def count_passes(spec: MiniConvSpec) -> int:
     return sum(-(-l.c_out // 4) for l in spec.layers)
 
 
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
 def _round4(c: int) -> int:
-    return -(-c // 4) * 4
+    return _round_up(c, 4)
 
 
-# TPU VMEM per core (~16 MB).  The fused kernel keeps the WHOLE micro-batch
-# input plus the final layer's padded intermediate resident on-chip, so the
-# deployable batch size is bounded by this budget (see
-# ``PassPlan.vmem_bytes`` / ``max_safe_batch``).
-DEFAULT_VMEM_LIMIT = 16 * 1024 * 1024
+# The VMEM a fused launch may use.  The fused kernel keeps the WHOLE
+# micro-batch input plus every layer's padded intermediate resident
+# on-chip, so the deployable batch size is bounded by this budget (see
+# ``PassPlan.vmem_bytes`` / ``max_safe_batch``), and the kernels hand the
+# same number to the compiler as ``vmem_limit_bytes``, so plan and
+# compiler agree on what fits.  A TPU v5e core has 128 MiB of VMEM; the
+# rest is left to the compiler's own scratch.
+DEFAULT_VMEM_LIMIT = 96 * 1024 * 1024
+
+
+def tiled_bytes(shape: Sequence[int], itemsize: int = 4) -> int:
+    """Bytes of one VMEM buffer of ``shape`` as the TPU compiler lays it
+    out: the minor dimension padded to 128 lanes, the second-minor to the
+    sublane tile (8 rows of 32-bit values, 16 of 16-bit, 32 of 8-bit),
+    leading dimensions as they are."""
+    *lead, sub, lane = (1,) * max(0, 2 - len(shape)) + tuple(shape)
+    sublanes = 8 * max(1, 4 // itemsize)
+    return (math.prod(lead) * _round_up(sub, sublanes) * _round_up(lane, 128)
+            * itemsize)
 
 
 # ---------------------------------------------------------------------------
@@ -267,61 +285,82 @@ class PassPlan:
         return max(p.samples for p in self.passes)
 
     # ---- VMEM residency of the fused kernel --------------------------------
-    def _vmem_terms(self, *, head: Optional[HeadPlan] = None,
-                    tile_h: int = 8, itemsize: int = 4) -> tuple[int, int]:
-        """(fixed_bytes, per_frame_bytes) of the fused-kernel VMEM residency.
-
-        Mirrors the allocation pattern of
-        ``repro.kernels.miniconv_pass.miniconv_encoder``: the whole-batch
-        padded input block (scales with B), the final layer's padded-input
-        scratch, per-layer padded weights/biases, one output tile, and —
-        with a fused head — the tiled lane-padded head weight plus the
-        projection scratch.  An estimate (the compiler adds its own
-        spills), but affine in batch, which is what the deployability
-        check needs.
-        """
-        first, last = self.layers[0], self.layers[-1]
+    def fused_tiling(self, tile_h: int = 8) -> tuple[int, int, int]:
+        """(tile_h, n_tiles, scratch_rows) of the fused kernel: ``tile_h``
+        clamped to the feature height, the number of output-row tiles,
+        and the rows of the final layer's padded input, over-allocated so
+        the last tile's reads stay in bounds."""
+        last = self.layers[-1]
         tile_h = max(1, min(tile_h, self.out_h))
         n_tiles = -(-self.out_h // tile_h)
         rows_need_max = (n_tiles * tile_h - 1) * last.stride + last.kernel
-        scratch_rows = max(last.padded_in_h, rows_need_max)
+        return tile_h, n_tiles, max(last.padded_in_h, rows_need_max)
+
+    def _vmem_terms(self, *, head: Optional[HeadPlan] = None,
+                    tile_h: int = 8, itemsize: int = 4,
+                    streamed: bool = False) -> tuple[int, int]:
+        """(fixed_bytes, per_frame_bytes) of the fused-kernel VMEM residency.
+
+        Mirrors the buffers of ``repro.kernels.miniconv_pass``'s fused
+        launch, each counted as the compiler lays it out
+        (:func:`tiled_bytes`) and twice when its block index changes
+        across the grid (the compiler double-buffers those): the batch
+        input block (scales with B; double-buffered when ``streamed``),
+        the padded-input scratch of layers 1..L-1, per-layer weights and
+        biases, the output tile and — with a fused head — the laid-out
+        head weight, its bias, the projection block and its accumulator.
+        Affine in batch, which is what the deployability check needs.
+        """
+        first, last = self.layers[0], self.layers[-1]
+        tile_h, n_tiles, scratch_rows = self.fused_tiling(tile_h)
         x0_rows = scratch_rows if len(self.layers) == 1 \
             else first.padded_in_h
-        per_frame = x0_rows * first.padded_in_w * first.c_in_pad * itemsize
-        fixed = tile_h * last.out_w * last.c_out_pad * itemsize  # out tile
-        if len(self.layers) > 1:
-            fixed += (scratch_rows * last.padded_in_w * last.c_in_pad
-                      * 4)                                       # fp32 scratch
-        for l in self.layers:
-            fixed += (l.kernel * l.kernel * l.c_in_pad * l.c_out_pad
-                      + l.c_out_pad) * itemsize                  # weights+bias
+        per_frame = (2 if streamed else 1) * tiled_bytes(
+            (x0_rows, first.padded_in_w, first.c_in_pad), itemsize)
+        fixed = 2 * tiled_bytes((tile_h, last.out_w, last.c_out_pad),
+                                itemsize)                        # out tile
+        for i, l in enumerate(self.layers):
+            fixed += (tiled_bytes((l.kernel, l.kernel, l.c_in_pad,
+                                   l.c_out_pad), itemsize)
+                      + tiled_bytes((1, l.c_out_pad), itemsize))
+            if i:                                   # fp32 padded-input scratch
+                rows = scratch_rows if l is last else l.padded_in_h
+                fixed += tiled_bytes((rows, l.padded_in_w, l.c_in_pad))
         if head is not None:
             if head.in_dim != self.flat_features:
                 raise ValueError(
                     f"head.in_dim {head.in_dim} != plan.flat_features "
                     f"{self.flat_features}")
-            d_pad = -(-head.out_dim // 128) * 128   # lane-padded for the MXU
-            tile_flat = tile_h * last.out_w * last.c_out_pad
-            fixed += n_tiles * tile_flat * d_pad * itemsize   # tiled weight
-            fixed += d_pad * (4 + 2 * itemsize)    # z scratch + bias + z out
+            d_pad = _round_up(head.out_dim, 128)         # lane-padded
+            fixed += tiled_bytes((n_tiles * tile_h * last.c_out_pad,
+                                  last.out_w, d_pad), itemsize)   # weight
+            fixed += tiled_bytes((1, d_pad), itemsize)            # bias
+            fixed += 2 * tiled_bytes((1, 1, d_pad), itemsize)     # z out
+            fixed += tiled_bytes((last.out_w, d_pad))             # z scratch
         return fixed, per_frame
 
     def vmem_bytes(self, batch: int = 1, *, head: Optional[HeadPlan] = None,
-                   tile_h: int = 8, itemsize: int = 4) -> int:
-        """Estimated VMEM bytes of ONE fused launch over a B-frame batch."""
+                   tile_h: int = 8, itemsize: int = 4,
+                   streamed: bool = False) -> int:
+        """VMEM bytes of ONE fused launch over a B-frame batch (or, with
+        ``streamed``, a B-frame chunk of the streamed launch)."""
         if batch < 1:
             raise ValueError(f"batch must be >= 1, got {batch}")
         fixed, per_frame = self._vmem_terms(head=head, tile_h=tile_h,
-                                            itemsize=itemsize)
+                                            itemsize=itemsize,
+                                            streamed=streamed)
         return fixed + batch * per_frame
 
     def max_safe_batch(self, *, head: Optional[HeadPlan] = None,
                        tile_h: int = 8, itemsize: int = 4,
-                       vmem_limit: int = DEFAULT_VMEM_LIMIT) -> int:
-        """Largest micro-batch whose fused launch fits the VMEM budget
-        (0 when even the batch-independent residency exceeds it)."""
+                       vmem_limit: int = DEFAULT_VMEM_LIMIT,
+                       streamed: bool = False) -> int:
+        """Largest micro-batch whose fused launch fits the VMEM budget —
+        with ``streamed``, the largest chunk of the streamed launch — (0
+        when even the batch-independent residency exceeds it)."""
         fixed, per_frame = self._vmem_terms(head=head, tile_h=tile_h,
-                                            itemsize=itemsize)
+                                            itemsize=itemsize,
+                                            streamed=streamed)
         return max(0, (vmem_limit - fixed) // per_frame)
 
     def check_batch(self, batch: int, *, head: Optional[HeadPlan] = None,
@@ -397,4 +436,4 @@ def build_pass_plan(spec: MiniConvSpec, h: int, w: Optional[int] = None, *,
 
 __all__ = ["DEFAULT_VMEM_LIMIT", "HeadPlan", "LayerPlan", "PassPlan",
            "ShaderPass", "build_pass_plan", "count_passes", "out_size",
-           "out_spatial_chain", "same_pads"]
+           "out_spatial_chain", "same_pads", "tiled_bytes"]

@@ -158,7 +158,7 @@ def estimated_cost_s(config, cand: Candidate) -> float:
             # launch groups below
             max_safe = plan.max_safe_batch(
                 head=head_plan if _fused_head(config, backend) else None,
-                tile_h=tile_h)
+                tile_h=tile_h, streamed=True)
             if max_safe >= 1 and micro > max_safe:
                 launches = math.ceil(micro / max_safe)
     t_launch = (launches * _LAUNCH_OVERHEAD_S + steps * _STEP_OVERHEAD_S
@@ -181,7 +181,8 @@ def vmem_feasible(config, cand: Candidate, *,
     plan, head_plan = _plan_and_head(config)
     head = head_plan if _fused_head(config, backend) else None
     max_safe = plan.max_safe_batch(head=head, tile_h=cand.tile_h,
-                                   vmem_limit=vmem_limit)
+                                   vmem_limit=vmem_limit,
+                                   streamed=backend.streamed)
     if backend.streamed:
         return max_safe >= 1
     return cand.micro_batch <= max_safe

@@ -48,7 +48,6 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
-import os
 from typing import Any, Callable, Optional
 
 import jax
@@ -62,6 +61,7 @@ from repro.core.split import SplitModel
 from repro.schema import check_version
 from repro.core.tuning import TunedPlan
 from repro.core.wire import CODECS, WireCodec, get_codec
+from repro.kernels.interpret import resolve_interpret
 from repro.nn.layers import dense
 from repro.rl.networks import Encoder, miniconv_encoder_init
 from repro.serving.client import EdgeClient
@@ -90,8 +90,9 @@ class DeploymentConfig:
                       ``xla`` | ``reference`` | ``grouped`` | ``fused`` |
                       ``fused+head``.
     interpret       : Pallas interpret (True) vs compiled (False) for the
-                      kernel backends; ``None`` = auto (compiled on TPU or
-                      with ``REPRO_PALLAS_COMPILE=1``).
+                      kernel backends; ``None`` = auto (interpreted on the
+                      CPU backend, compiled on the chip —
+                      ``repro.kernels.interpret.resolve_interpret``).
     codec           : wire-codec name (``repro.core.wire.CODECS``).
     head_dim        : width of the server-side projection (paper: 512).
     head_act        : activation of the projection.
@@ -270,15 +271,14 @@ class Deployment:
         A manifest ``tuning`` block overrides the executed backend /
         ``tile_h`` / micro-batch (tune once, serve everywhere).  When the
         resolved backend runs the fused Pallas kernel compiled
-        (``interpret=False``, or ``interpret=None`` resolving to compiled
-        on a TPU host / under ``REPRO_PALLAS_COMPILE=1``), the configured
-        micro-batch is checked against the fused kernel's VMEM residency
-        model — and an over-budget batch is no longer rejected: it is
-        PIPELINED through :func:`~repro.kernels.miniconv_pass.
-        miniconv_encoder_stream` in ``max_safe_batch``-frame chunks (the
-        decision is recorded in ``build_log``).  Build still fails, with
-        the computed ``max_safe_batch`` and the tuner's suggestion, when
-        even a single frame exceeds the budget.
+        (``interpret=False``, or ``interpret=None`` on an accelerator),
+        the configured micro-batch is checked against the fused kernel's
+        VMEM residency model — and an over-budget batch is no longer
+        rejected: it is PIPELINED through :func:`~repro.kernels.
+        miniconv_pass.miniconv_encoder_stream` in chunks that fit the
+        streamed launch (the decision is recorded in ``build_log``).
+        Build still fails, with the computed ``max_safe_batch`` and the
+        tuner's suggestion, when even a single frame exceeds the budget.
         """
         config.validate()
         backend = get_backend(config.backend)
@@ -299,31 +299,34 @@ class Deployment:
                                             and backend.mode == "fused")
         vmem_head = head_plan if fused_head else None
         max_safe = plan.max_safe_batch(head=vmem_head, tile_h=tile_h)
+        # a streamed chunk's input block is double-buffered
+        chunk_safe = plan.max_safe_batch(head=vmem_head, tile_h=tile_h,
+                                         streamed=True)
         # The VMEM residency model describes the FUSED kernel (whole-batch
         # input resident on-chip); per-pass/grouped kernels stream row
         # blocks and are batch-size-indifferent.  interpret=None resolves
-        # the same way the kernel layer does, so a default manifest built
-        # on a TPU host (compiled) is still checked at build time.
-        if config.interpret is None:
-            compiled = bool(os.environ.get("REPRO_PALLAS_COMPILE")) \
-                or jax.default_backend() == "tpu"
-        else:
-            compiled = not config.interpret
+        # through the kernel layer's own switch, so a default manifest
+        # built on the chip (compiled) is checked at build time.
+        compiled = not resolve_interpret(config.interpret)
         stream_chunk: Optional[int] = None
         if backend.mode == "fused":
-            if compiled and max_safe < 1:
-                raise cls._unlaunchable(config, plan, vmem_head, tile_h)
+            streams = backend.streamed or config.max_batch > max_safe
+            if compiled and (max_safe < 1 or (streams and chunk_safe < 1)):
+                raise cls._unlaunchable(config, plan, vmem_head, tile_h,
+                                        streamed=max_safe >= 1)
             if backend.streamed:
                 chunk = tuning.micro_batch if tuning is not None else 0
                 if compiled:
-                    chunk = min(chunk, max_safe) if chunk >= 1 else max_safe
+                    chunk = (min(chunk, chunk_safe) if chunk >= 1
+                             else chunk_safe)
                 elif chunk < 1:
-                    chunk = max_safe if max_safe >= 1 else config.max_batch
+                    chunk = chunk_safe if chunk_safe >= 1 \
+                        else config.max_batch
                 stream_chunk = max(1, min(chunk, config.max_batch))
-            elif compiled and config.max_batch > max_safe:
+            elif compiled and streams:
                 # Over-budget micro-batch on the plain fused path: pipeline
                 # it instead of rejecting the deployment.
-                stream_chunk = max_safe
+                stream_chunk = chunk_safe
                 log.append(cls._pipelining_note(config, max_safe, tile_h,
                                                 stream_chunk))
         codec = get_codec(config.codec)
@@ -402,12 +405,16 @@ class Deployment:
                 f"--tune to measure and freeze)")
 
     @classmethod
-    def _unlaunchable(cls, config, plan, vmem_head, tile_h) -> ValueError:
-        need = plan.vmem_bytes(1, head=vmem_head, tile_h=tile_h)
+    def _unlaunchable(cls, config, plan, vmem_head, tile_h,
+                      streamed=False) -> ValueError:
+        need = plan.vmem_bytes(1, head=vmem_head, tile_h=tile_h,
+                               streamed=streamed)
         from repro.core.passplan import DEFAULT_VMEM_LIMIT
         return ValueError(
             f"compiled fused launch cannot fit VMEM at ANY batch size: one "
-            f"{plan.in_h}x{plan.in_w} frame needs ~{need / 2**20:.2f} MiB "
+            f"{plan.in_h}x{plan.in_w} frame"
+            f"{' (double-buffered, streamed)' if streamed else ''} needs "
+            f"~{need / 2**20:.2f} MiB "
             f"> budget {DEFAULT_VMEM_LIMIT / 2**20:.2f} MiB "
             f"(max_safe_batch=0, tile_h={tile_h}) — batch pipelining "
             f"cannot help; lower the input size or split the spec"
@@ -590,14 +597,16 @@ class Deployment:
               timeout_s: float = 10.0, retries: int = 2,
               precompile: bool = True, start: bool = True,
               shaping=None):
-        """A REAL multi-process fleet for THIS deployment (localhost).
+        """A REAL fleet for THIS deployment (localhost).
 
-        The counterpart of :meth:`fleet_sim`: ``n_servers`` spawned
-        worker processes (each rebuilding the jitted server half from
-        this manifest), length-prefix-framed sockets carrying the wire
-        codec's payloads, and the registered routing policy at the front
-        door (``repro.serving.realfleet``).  Fleet shape defaults to the
-        manifest (``n_servers`` / ``router`` / ``max_batch``), exactly
+        The counterpart of :meth:`fleet_sim`: ``n_servers`` workers (each
+        rebuilding the jitted server half from this manifest),
+        length-prefix-framed sockets carrying the wire codec's payloads,
+        and the registered routing policy at the front door
+        (``repro.serving.realfleet``).  On the CPU the workers are
+        spawned processes; on an accelerator they run in this process,
+        replica ``i`` on ``jax.devices()[i]``.  Fleet shape defaults to
+        the manifest (``n_servers`` / ``router`` / ``max_batch``), exactly
         like the simulator.
 
         When a measured ``service_model`` is given, worker admission is
@@ -611,7 +620,7 @@ class Deployment:
         the measured counterpart of the sims' shaped uplink.
 
         Returns a started :class:`~repro.serving.realfleet.RealFleet`
-        (``start=False`` defers the spawn); always ``close()`` it — the
+        (``start=False`` defers the start); always ``close()`` it — the
         returned leak list is the CI "no leaked workers" gate.
         """
         import numpy as np
@@ -786,6 +795,8 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()
 
 

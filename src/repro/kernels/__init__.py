@@ -19,7 +19,8 @@ Module map
     Pure-jnp oracles every kernel here is parity-tested against.
 ``flash_attention``
     Blocked (flash) attention prefill kernel for the baselines.
-``pallas_compat``
-    Pallas API version shims plus ``compiled_pallas_supported()``, the
-    probe gating the ``REPRO_PALLAS_COMPILE=1`` compiled-path tier.
+``interpret``
+    ``resolve_interpret``, the one interpret/compile switch: kernels are
+    interpreted on the CPU backend (or when a caller passes
+    ``interpret=True``) and compiled everywhere else.
 """

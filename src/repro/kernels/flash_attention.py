@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import tpu_compiler_params
 
 _NEG_INF = -1e30
 
@@ -104,7 +103,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -21,59 +21,59 @@ execution tiers (see ``repro.core.passplan`` for the schedule itself):
 
 3. :func:`miniconv_encoder` — one pallas_call for the WHOLE encoder
    (the fused analogue of the paper's full pass sequence).  grid =
-   (batch, out_row_tile); layer intermediates never leave the chip:
-   layers 0..L-2 are computed once per batch element (on the first tile
-   step) and the SAME-padded input of the final layer is parked in a VMEM
-   scratch, from which every grid step computes ``tile_h`` rows of the
-   final feature map (multi-row output tiling).  All output groups of a
-   layer are produced by a single (H*W, C_in) @ (C_in, C_out) matmul.
-   Channel counts are zero-padded to multiples of 4 (RGBA packing), so
-   specs with c_out % 4 != 0 execute correctly; the wrapper slices the
-   result back to the true channel count.
+   (chunk, batch, out_row_tile); layer intermediates never leave the
+   chip: layers 0..L-2 run once per batch element (on the first tile
+   step), one output row at a time, each row stored into the interior of
+   the next layer's zero-bordered (SAME-padded) VMEM buffer; every grid
+   step then computes ``tile_h`` rows of the final feature map from the
+   last buffer (multi-row output tiling).  Every (i, j) tap of a row is
+   one strided ref load (``pl.ds(..., stride=s)`` — the TPU lowers
+   strided ref loads, not strided value slices) and one (W_out, C_in) @
+   (C_in, C_out) matmul at ``Precision.HIGHEST``: all output groups of a
+   layer in a single contraction, fp32 throughout.  Channel counts are
+   zero-padded to multiples of 4 (RGBA packing), so specs with c_out % 4
+   != 0 execute correctly; the wrapper slices the result back.
 
-   The batch dimension is the OUTER grid dimension, so a (B, H, W, C)
-   input is a single kernel launch: weight padding, dispatch, and the
-   interpreter setup are paid once for the whole micro-batch instead of
-   once per frame (the batched-serving path; see
+   The batch dimension is an outer grid dimension, so a (B, H, W, C)
+   input is a single kernel launch: weight padding and dispatch are paid
+   once for the whole micro-batch (the batched-serving path; see
    ``repro.serving.server.BatchingPolicyServer``).
 
    Optionally the server-side linear projection (the ``rl.networks``
-   flatten + dense head) is FUSED into the kernel epilogue: each tile's
-   activated rows are immediately contracted against the matching row
-   slice of the head weight and accumulated in a (1, D) VMEM scratch, so
-   the (B, D) projection leaves the kernel without the feature map ever
-   being re-read from HBM.  Head-weight rows beyond ``plan.out_h`` and
-   channels beyond ``plan.k_out`` are zero-padded, which cancels the
-   contributions of the over-allocated tile rows and RGBA padding
-   channels; the projection width D is lane-padded to a multiple of 128
-   so the epilogue matmul fills whole MXU lanes (the zero columns are
-   sliced off the returned projection).
+   flatten + dense head) is FUSED into the kernel epilogue: each output
+   row's channels are weighted against their (W_out, D) head-weight
+   slices and accumulated in a (W_out, D) VMEM scratch, summed over W_out
+   on the last tile, so the (B, D) projection leaves the kernel without
+   the feature map ever being re-read from HBM.  Head-weight rows beyond
+   ``plan.out_h`` and channels beyond ``plan.k_out`` are zero, which
+   cancels the over-allocated tile rows and RGBA padding channels; the
+   projection width D is lane-padded to a multiple of 128 (the zero
+   columns are sliced off the returned projection).
 
 4. :func:`miniconv_encoder_stream` — the fused encoder pipelined over
    BATCH CHUNKS, lifting the batch-must-fit-VMEM rule
    (``PassPlan.max_safe_batch``).  The micro-batch is split into
-   ``chunk_b``-frame chunks; on compiled TPU a single pallas_call with a
-   (chunk, batch, tile) grid fetches each chunk's input block HBM->VMEM
-   per grid step (Pallas double-buffers the next chunk's fetch behind the
-   current chunk's compute), while the portable fallback issues one fused
-   launch per chunk (automatic multi-launch splitting).  When the batch
-   divides into whole chunks both strategies are bitwise equal to calling
-   :func:`miniconv_encoder` chunk-by-chunk and concatenating, so
-   arbitrarily large micro-batches stream through one server (see
-   :func:`miniconv_encoder_stream` for the ragged-remainder contract).
-   Registered as the ``fused+stream`` execution backend
-   (``repro.core.backends``).
+   ``chunk_b``-frame chunks; compiled, one pallas_call whose chunk grid
+   dimension fetches each chunk's input block HBM->VMEM (double-buffered
+   behind the previous chunk's compute); in interpret mode, one fused
+   launch per chunk.  When the batch divides into whole chunks both
+   strategies are bitwise equal to calling :func:`miniconv_encoder`
+   chunk-by-chunk and concatenating.  Registered as the ``fused+stream``
+   execution backend (``repro.core.backends``).
+
+Every fused launch hands the compiler ``vmem_limit_bytes`` =
+``repro.core.passplan.DEFAULT_VMEM_LIMIT``, the budget the PassPlan's
+residency model (``PassPlan.vmem_bytes``) checks against.  The per-pass
+and grouped tiers (1, 2) are interpret-mode oracles: their strided value
+slices do not lower for the TPU, so on a chip they fail with the
+compiler's error.
 
 Stride-2 passes subsample the input rows/cols, mirroring the shader's
-half-resolution render target.  On very large inputs the fused kernel keeps
-the full input image plus the last intermediate in VMEM (~a few MB at
-X=400); for bigger frames lower ``tile_h`` does not help — split the spec
-or stream the batch (:func:`miniconv_encoder_stream`).
+half-resolution render target.
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -81,7 +81,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.miniconv import _ACTS
-from repro.kernels.pallas_compat import tpu_compiler_params
+from repro.core.passplan import DEFAULT_VMEM_LIMIT
+from repro.kernels.interpret import resolve_interpret
 
 
 # ---------------------------------------------------------------------------
@@ -124,7 +125,7 @@ def _pass_kernel(x_ref, w_ref, b_ref, o_ref, acc_ref, *, stride: int,
 
 @functools.partial(jax.jit,
                    static_argnames=("stride", "interpret"))
-def miniconv_pass(x, w, b, *, stride: int = 1, interpret: bool = True):
+def miniconv_pass(x, w, b, *, stride: int = 1, interpret=None):
     """One shader pass on a pre-padded input (VALID convolution).
 
     x: (B, H_in, W_in, C_in); w: (kh, kw, C_in, 4); b: (4,).
@@ -151,9 +152,9 @@ def miniconv_pass(x, w, b, *, stride: int = 1, interpret: bool = True):
                                lambda b_, q, i: (b_, q, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, h_out, w_out, 4), x.dtype),
         scratch_shapes=[pltpu.VMEM((w_out, 4), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, w, b.reshape(1, 4))
 
 
@@ -199,8 +200,7 @@ def _layer_group_kernel(x_ref, w_ref, b_ref, o_ref, acc_ref, *, stride: int,
 
 
 @functools.partial(jax.jit, static_argnames=("stride", "interpret"))
-def miniconv_layer_grouped(x, w, b, *, stride: int = 1,
-                           interpret: bool = True):
+def miniconv_layer_grouped(x, w, b, *, stride: int = 1, interpret=None):
     """All output groups of one layer in a single pallas_call (VALID conv).
 
     x: (B, H_in, W_in, C_in); w: (kh, kw, C_in, C_out) with C_out % 4 == 0
@@ -229,10 +229,10 @@ def miniconv_layer_grouped(x, w, b, *, stride: int = 1,
                                lambda b_, q, i, g: (b_, q, 0, g)),
         out_shape=jax.ShapeDtypeStruct((B, h_out, w_out, c_out), x.dtype),
         scratch_shapes=[pltpu.VMEM((n_groups, w_out, 4), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, w, b.reshape(n_groups, 4))
 
 
@@ -240,57 +240,64 @@ def miniconv_layer_grouped(x, w, b, *, stride: int = 1,
 # Tier 3: the whole encoder as ONE fused kernel
 # ---------------------------------------------------------------------------
 
-def _conv_from_padded(xp, w, b, *, out_h: int, out_w: int, stride: int,
-                      kernel: int):
-    """SAME conv of a pre-padded fp32 image held in VMEM.
+_HIGHEST = jax.lax.Precision.HIGHEST      # fp32 MXU contraction on TPU
 
-    xp: (H_pad, W_pad, C_in); w: (k, k, C_in, C_out); b: (C_out,).
-    Returns (out_h, out_w, C_out) fp32.  Each (i, j) tap is one
-    (out_h*out_w, C_in) @ (C_in, C_out) MXU matmul — all output groups of
-    the layer in a single contraction.
+
+def _conv_row(src_ref, lead, r, w_ref, bias, m):
+    """Output row ``r`` of layer ``m``'s SAME conv, read from its padded
+    input parked in VMEM (``src_ref[*lead]``: (H_pad, W_pad, C_in_pad)).
+
+    Each (i, j) tap is one strided ref load of the ``out_w`` input columns
+    it samples (``pl.ds`` with the layer's stride: the TPU lowers strided
+    REF loads, not strided value slices) and one (out_w, C_in) @ (C_in,
+    C_out) MXU matmul — all output groups of the layer in a single
+    contraction.  Returns the activated (out_w, C_out_pad) fp32 row.
     """
-    c_in = xp.shape[-1]
-    c_out = w.shape[-1]
-    acc = jnp.broadcast_to(b, (out_h, out_w, c_out)).astype(jnp.float32)
-    for i in range(kernel):
-        for j in range(kernel):
-            win = jax.lax.slice(
-                xp, (i, j, 0),
-                (i + (out_h - 1) * stride + 1,
-                 j + (out_w - 1) * stride + 1, c_in),
-                (stride, stride, 1))              # (out_h, out_w, C_in)
-            tap = win.reshape(out_h * out_w, c_in) @ w[i, j]
-            acc = acc + tap.reshape(out_h, out_w, c_out)
-    return acc
+    acc = jnp.broadcast_to(bias, (m.out_w, m.c_out_pad))
+    for i in range(m.kernel):
+        for j in range(m.kernel):
+            win = src_ref[(*lead, r * m.stride + i,
+                           pl.ds(j, m.out_w, stride=m.stride), slice(None))]
+            acc = acc + jnp.dot(win.astype(jnp.float32),
+                                w_ref[i, j].astype(jnp.float32),
+                                precision=_HIGHEST,
+                                preferred_element_type=jnp.float32)
+    return _ACTS[m.activation](acc)
 
 
-def _encoder_kernel(*refs, plan, tile_h: int, scratch_rows: int,
-                    has_head: bool, head_act: str, streamed: bool = False):
-    """One (batch, out_row_tile) grid step of the fused encoder.
+def _front_layer(src_ref, lead, dst_ref, w_ref, b_ref, m, nxt):
+    """Run layer ``m`` over a whole frame, writing its activated output
+    into the interior of ``dst_ref`` — the zero-bordered (SAME-padded)
+    input of the next layer ``nxt``."""
+    dst_ref[...] = jnp.zeros(dst_ref.shape, jnp.float32)
+    bias = b_ref[...].astype(jnp.float32)
+
+    def row(r, carry):
+        dst_ref[nxt.pad_top + r, pl.ds(nxt.pad_left, m.out_w)] = _conv_row(
+            src_ref, lead, r, w_ref, bias, m)
+        return carry
+
+    jax.lax.fori_loop(0, m.out_h, row, 0)
+
+
+def _encoder_kernel(*refs, plan, tile_h: int, has_head: bool, head_act: str):
+    """One (chunk, batch, out_row_tile) grid step of the fused encoder.
 
     refs layout: x_ref, w_0..w_{L-1}, b_0..b_{L-1}[, hw_ref, hb_ref],
-    o_ref[, z_ref][, p_scr][, z_scr].
-    ``p_scr`` (absent when L == 1) holds the SAME-padded input of the final
-    layer for the current batch element: (scratch_rows, W_pad, C_in_pad)
-    fp32, built once on the first tile step and reused by every tile.
-    With a fused head, ``hw_ref`` is the FULL (n_tiles, tile_h*W_out*
-    C_out_pad, D) tiled head weight, ``z_scr`` the (1, D) fp32 projection
-    accumulator and ``z_ref`` the (1, D) projection output block.
+    o_ref[, z_ref], buf_1..buf_{L-1}[, z_scr].
+    ``buf_l`` holds the SAME-padded input of layer l for the current batch
+    element, (rows, W_pad, C_in_pad) fp32: layers 0..L-2 fill them once,
+    on the first tile step, and the final layer's buffer is over-allocated
+    to ``scratch_rows`` so every tile's reads stay in bounds.  With a fused
+    head, ``hw_ref`` is the (rows * C_out_pad, W_out, D) head weight laid
+    out by :func:`prepare_fused_head`, ``z_scr`` the (W_out, D) fp32
+    projection accumulator and ``z_ref`` the (1, 1, D) projection block.
 
-    ``x_ref`` and ``hw_ref`` are whole-array blocks (constant index maps);
-    the kernel slices out the (batch, tile) pieces it needs with pl.ds.
-    Per-step sub-array BlockSpec fetches are pathologically slow in
-    interpret mode (~1 ms/MB, re-fetched every grid step) and the x block
-    is only consumed on the first tile step anyway; whole-array blocks
-    skip the copy entirely.  Compiled-TPU consequence: the whole
-    micro-batch input must fit VMEM (~1 MB at the serving scale B=8,
-    X=84; stream the batch above that — see ``streamed``).
-
-    With ``streamed=True`` the grid gains a leading batch-CHUNK dimension,
-    ``x_ref`` is one chunk's input block (re-fetched HBM->VMEM when the
-    chunk index advances; Pallas double-buffers that fetch behind the
-    previous chunk's compute on compiled TPU) and ``b_i`` indexes WITHIN
-    the chunk — so only ``chunk_b`` frames are VMEM-resident at a time.
+    ``x_ref`` is one batch chunk's input block — the whole micro-batch
+    unless the launch streams (:func:`_fused_launch`) — and ``hw_ref`` a
+    whole-array block; the kernel indexes the batch element ``b_i``
+    within the chunk itself.  The chunk must fit VMEM:
+    ``PassPlan.max_safe_batch``.
     """
     layers = plan.layers
     L = len(layers)
@@ -303,99 +310,84 @@ def _encoder_kernel(*refs, plan, tile_h: int, scratch_rows: int,
     o_ref = refs[n_in]
     z_ref = refs[n_in + 1] if has_head else None
     scr = refs[n_in + (2 if has_head else 1):]
-    p_scr = scr[0] if L > 1 else None
+    bufs = scr[:L - 1]
     z_scr = scr[-1] if has_head else None
-    b_i = pl.program_id(1 if streamed else 0)
-    t = pl.program_id(2 if streamed else 1)
-    tile_dim = 2 if streamed else 1
+    b_i = pl.program_id(1)
+    t = pl.program_id(2)
     last = layers[-1]
 
     if L > 1:
         @pl.when(t == 0)
         def _chain_front_layers():
             # Layers 0..L-2 run once per batch element; intermediates stay
-            # on-chip and the final layer's padded input is parked in VMEM.
-            y = x_ref[pl.ds(b_i, 1)][0].astype(jnp.float32)  # padded input
+            # on-chip in the padded-input buffers.
             for l in range(L - 1):
-                m = layers[l]
-                y = _conv_from_padded(
-                    y, w_refs[l][...].astype(jnp.float32),
-                    b_refs[l][0].astype(jnp.float32),
-                    out_h=m.out_h, out_w=m.out_w, stride=m.stride,
-                    kernel=m.kernel)
-                y = _ACTS[m.activation](y)
-                nxt = layers[l + 1]
-                pad = jnp.zeros((scratch_rows if l == L - 2
-                                 else nxt.padded_in_h,
-                                 nxt.padded_in_w, nxt.c_in_pad), jnp.float32)
-                y = jax.lax.dynamic_update_slice(
-                    pad, y, (nxt.pad_top, nxt.pad_left, 0))
-            p_scr[...] = y
-
-        src_ref = p_scr
-    else:
-        src_ref = None
-
-    # Final layer: tile_h output rows per grid step.
-    rows_need = (tile_h - 1) * last.stride + last.kernel
-    row0 = t * tile_h * last.stride
-    if L > 1:
-        xp = src_ref[pl.ds(row0, rows_need)]
-    else:
-        xp = x_ref[pl.ds(b_i, 1),
-                   pl.ds(row0, rows_need)][0].astype(jnp.float32)
-    acc = _conv_from_padded(
-        xp, w_refs[-1][...].astype(jnp.float32),
-        b_refs[-1][0].astype(jnp.float32),
-        out_h=tile_h, out_w=last.out_w, stride=last.stride,
-        kernel=last.kernel)
-    y = _ACTS[last.activation](acc)
-    o_ref[0] = y.astype(o_ref.dtype)
+                src, lead = (x_ref, (b_i,)) if l == 0 else (bufs[l - 1], ())
+                _front_layer(src, lead, bufs[l], w_refs[l], b_refs[l],
+                             layers[l], layers[l + 1])
 
     if has_head:
-        # Fused projection epilogue: contract this tile's activated rows
-        # against the matching head-weight rows.  Zero-padded weight rows
-        # (beyond plan.out_h) and channels (beyond plan.k_out) null the
-        # over-allocated tile rows and RGBA padding.
         @pl.when(t == 0)
         def _z_init():
-            z_scr[...] = jnp.broadcast_to(
-                hb_ref[0].astype(jnp.float32), z_scr.shape)
+            z_scr[...] = jnp.zeros(z_scr.shape, jnp.float32)
 
-        z_scr[...] = z_scr[...] + (
-            y.reshape(1, -1) @ hw_ref[pl.ds(t, 1)][0].astype(jnp.float32))
+    # Final layer: tile_h output rows per grid step.
+    src, lead = (bufs[-1], ()) if L > 1 else (x_ref, (b_i,))
+    bias = b_refs[-1][...].astype(jnp.float32)
 
-        @pl.when(t == pl.num_programs(tile_dim) - 1)
+    def tile_row(k, carry):
+        r = t * tile_h + k
+        y = _conv_row(src, lead, r, w_refs[-1], bias, last)
+        o_ref[0, k] = y.astype(o_ref.dtype)
+        if has_head:
+            # Fused projection epilogue: weight each output channel's
+            # column of this row against its (W_out, D) head-weight slice.
+            # Zero-padded weight rows (beyond plan.out_h) and channels
+            # (beyond plan.k_out) null the over-allocated tile rows and
+            # the RGBA padding.
+            z = z_scr[...]
+            for c in range(last.c_out_pad):
+                z = z + y[:, c:c + 1] * hw_ref[r * last.c_out_pad + c].astype(
+                    jnp.float32)
+            z_scr[...] = z
+        return carry
+
+    jax.lax.fori_loop(0, tile_h, tile_row, 0)
+
+    if has_head:
+        @pl.when(t == pl.num_programs(2) - 1)
         def _z_flush():
-            z_ref[0] = _ACTS[head_act](z_scr[...])[0].astype(z_ref.dtype)
+            z = (jnp.sum(z_scr[...], axis=0, keepdims=True)
+                 + hb_ref[...].astype(jnp.float32))
+            z_ref[0] = _ACTS[head_act](z).astype(z_ref.dtype)
 
 
-def _tile_head(head_w, plan, *, tile_h: int, n_tiles: int):
-    """Lay a (plan.flat_features, D) head weight out on the kernel's tiled
-    feature order: (n_tiles, tile_h*W_out*C_out_pad, D), zero rows beyond
-    plan.out_h / channels beyond plan.k_out (they cancel the final tile's
-    over-allocated rows and the RGBA padding)."""
+def _tile_head(head_w, plan, *, rows: int):
+    """Lay a (plan.flat_features, D) head weight out for the kernel's
+    epilogue: (rows * C_out_pad, W_out, D), one (W_out, D) slice per
+    (feature row, channel), zero beyond plan.out_h rows and plan.k_out
+    channels (they cancel the final tile's over-allocated rows and the
+    RGBA padding)."""
     last = plan.layers[-1]
-    flat = plan.out_h * plan.out_w * plan.k_out
-    assert head_w.shape[0] == flat, (head_w.shape, flat)
+    assert head_w.shape[0] == plan.flat_features, (head_w.shape,
+                                                   plan.flat_features)
     d_out = head_w.shape[1]
     hw = head_w.reshape(plan.out_h, plan.out_w, plan.k_out, d_out)
-    hw_pad = jnp.zeros((n_tiles * tile_h, last.out_w, last.c_out_pad,
-                        d_out), head_w.dtype)
-    hw_pad = jax.lax.dynamic_update_slice(hw_pad, hw, (0, 0, 0, 0))
-    return hw_pad.reshape(n_tiles, tile_h * last.out_w * last.c_out_pad,
-                          d_out)
+    hw = jnp.pad(hw.transpose(0, 2, 1, 3),
+                 ((0, rows - plan.out_h), (0, last.c_out_pad - plan.k_out),
+                  (0, 0), (0, 0)))
+    return hw.reshape(rows * last.c_out_pad, plan.out_w, d_out)
 
 
 @functools.partial(jax.jit, static_argnames=("plan", "tile_h"))
 def prepare_fused_head(head_w, plan, *, tile_h: int = 8):
     """Pre-tile a (plan.flat_features, D) head weight for the fused-head
     epilogue.  :func:`miniconv_encoder` tiles a 2-D ``head_w`` per call
-    (inside the launch, a multi-MB zeros+copy); hot serving paths should
+    (inside the launch, a multi-MB transpose+pad); hot serving paths should
     call this ONCE per head and pass the 3-D result instead."""
     tile_h = max(1, min(tile_h, plan.out_h))
     n_tiles = -(-plan.out_h // tile_h)
-    return _tile_head(head_w, plan, tile_h=tile_h, n_tiles=n_tiles)
+    return _tile_head(head_w, plan, rows=n_tiles * tile_h)
 
 
 def miniconv_encoder(x, weights, biases, plan, *, tile_h: int = 8,
@@ -417,14 +409,9 @@ def miniconv_encoder(x, weights, biases, plan, *, tile_h: int = 8,
     A 3-D ``head_w`` is taken as already tiled by :func:`prepare_fused_head`
     (with the SAME ``tile_h``), skipping the per-call tiling copy.
     """
-    # resolve the env-dependent default OUTSIDE the jit cache so flipping
-    # REPRO_PALLAS_COMPILE between calls is honoured
-    if interpret is None:
-        interpret = (not os.environ.get("REPRO_PALLAS_COMPILE")
-                     and jax.default_backend() != "tpu")
-    return _miniconv_encoder(x, weights, biases, plan, tile_h=tile_h,
-                             head_w=head_w, head_b=head_b,
-                             head_act=head_act, interpret=interpret)
+    return _fused_launch(x, weights, biases, plan, tile_h=tile_h,
+                         head_w=head_w, head_b=head_b, head_act=head_act,
+                         interpret=resolve_interpret(interpret))
 
 
 def _prep_fused_inputs(x, weights, biases, plan, *, tile_h: int,
@@ -446,13 +433,8 @@ def _prep_fused_inputs(x, weights, biases, plan, *, tile_h: int,
     assert c_in == layers[0].c_in and len(weights) == L == len(biases)
     has_head = head_w is not None
 
-    tile_h = max(1, min(tile_h, plan.out_h))
-    n_tiles = -(-plan.out_h // tile_h)
+    tile_h, n_tiles, scratch_rows = plan.fused_tiling(tile_h)
     last = layers[-1]
-    # Rows the last tile may read past the exact padded input: over-allocate
-    # zero rows at the bottom so every pl.ds stays in bounds.
-    rows_need_max = (n_tiles * tile_h - 1) * last.stride + last.kernel
-    scratch_rows = max(last.padded_in_h, rows_need_max)
 
     # Zero-pad channels to RGBA multiples and bake in layer-0 SAME padding.
     first = layers[0]
@@ -473,17 +455,16 @@ def _prep_fused_inputs(x, weights, biases, plan, *, tile_h: int,
 
     hw_pad = hb = None
     d_out = d_pad = 0
-    tile_flat = tile_h * last.out_w * last.c_out_pad
+    head_rows = n_tiles * tile_h * last.c_out_pad
     if has_head:
         if head_w.ndim == 3:              # pre-tiled by prepare_fused_head
-            assert head_w.shape[:2] == (n_tiles, tile_flat), \
-                (head_w.shape, n_tiles, tile_flat)
+            assert head_w.shape[:2] == (head_rows, last.out_w), \
+                (head_w.shape, head_rows, last.out_w)
             hw_pad = head_w
         else:
-            hw_pad = _tile_head(head_w, plan, tile_h=tile_h,
-                                n_tiles=n_tiles)
+            hw_pad = _tile_head(head_w, plan, rows=n_tiles * tile_h)
         # Lane-pad the projection width to a multiple of 128 so the
-        # epilogue matmul fills whole MXU lanes (D=512 is already aligned;
+        # epilogue fills whole vector lanes (D=512 is already aligned;
         # ragged widths gain zero columns that are sliced off below).
         d_out = hw_pad.shape[-1]
         d_pad = -(-d_out // 128) * 128
@@ -495,154 +476,97 @@ def _prep_fused_inputs(x, weights, biases, plan, *, tile_h: int,
             hb = jnp.pad(hb, ((0, d_pad - d_out),))
         hb = hb.reshape(1, d_pad)
 
-    scratch_shapes = []
-    if L > 1:
-        scratch_shapes.append(pltpu.VMEM(
-            (scratch_rows, last.padded_in_w, last.c_in_pad), jnp.float32))
+    # SAME-padded inputs of layers 1..L-1; the last is over-allocated to
+    # scratch_rows so every tile's reads stay in bounds.
+    scratch_shapes = [
+        pltpu.VMEM((scratch_rows if l == L - 1 else m.padded_in_h,
+                    m.padded_in_w, m.c_in_pad), jnp.float32)
+        for l, m in enumerate(layers) if l > 0]
     if has_head:
-        scratch_shapes.append(pltpu.VMEM((1, d_pad), jnp.float32))
+        scratch_shapes.append(pltpu.VMEM((last.out_w, d_pad), jnp.float32))
 
     return dict(xp=xp, ws=ws, bs=bs, hw_pad=hw_pad, hb=hb,
                 has_head=has_head, tile_h=tile_h, n_tiles=n_tiles,
-                tile_flat=tile_flat, scratch_rows=scratch_rows,
-                x0_rows=x0_rows, d_out=d_out, d_pad=d_pad,
-                scratch_shapes=scratch_shapes, B=B, L=L,
-                first=first, last=last)
+                head_rows=head_rows, x0_rows=x0_rows, d_out=d_out,
+                d_pad=d_pad, scratch_shapes=scratch_shapes, first=first,
+                last=last)
 
 
 @functools.partial(jax.jit, static_argnames=("plan", "tile_h", "head_act",
-                                             "interpret"))
-def _miniconv_encoder(x, weights, biases, plan, *, tile_h: int,
-                      head_w, head_b, head_act: str, interpret: bool):
+                                             "interpret", "chunk_b"))
+def _fused_launch(x, weights, biases, plan, *, tile_h: int, head_w, head_b,
+                  head_act: str, interpret: bool, chunk_b=None):
+    """ONE pallas_call over a (n_chunks, chunk_b, n_tiles) grid.
+
+    ``chunk_b=None`` makes the whole batch one chunk: the input is a
+    whole-array block with a constant index map, fetched once and held in
+    one VMEM buffer.  Otherwise the input BlockSpec covers one
+    ``chunk_b``-frame chunk and its index map advances with the chunk grid
+    dimension, so only that chunk is VMEM-resident; the compiler
+    double-buffers it, fetching chunk c+1 HBM->VMEM while chunk c
+    computes.  The batch is zero-padded up to a whole number of chunks;
+    padded frames compute garbage that is sliced off (each batch element
+    is independent, so real frames are bitwise unaffected).
+    """
+    B = x.shape[0]
+    chunk = B if chunk_b is None else chunk_b
+    n_chunks = -(-B // chunk)
+    b_pad = n_chunks * chunk
+    if b_pad != B:
+        x = jnp.pad(x, ((0, b_pad - B), (0, 0), (0, 0), (0, 0)))
     p = _prep_fused_inputs(x, weights, biases, plan, tile_h=tile_h,
                            head_w=head_w, head_b=head_b)
-    B, L, first, last = p["B"], p["L"], p["first"], p["last"]
+    first, last = p["first"], p["last"]
     tile_h, n_tiles = p["tile_h"], p["n_tiles"]
 
-    # Whole-array block (constant index map): the kernel slices out the
-    # batch element itself — see the interpret-mode fetch note in
-    # _encoder_kernel's docstring.
-    in_specs = [pl.BlockSpec(
-        (B, p["x0_rows"], first.padded_in_w, first.c_in_pad),
-        lambda b_, t: (0, 0, 0, 0))]
-    for l in range(L):
-        m = plan.layers[l]
-        in_specs.append(pl.BlockSpec(
-            (m.kernel, m.kernel, m.c_in_pad, m.c_out_pad),
-            lambda b_, t: (0, 0, 0, 0)))
-    for l in range(L):
-        m = plan.layers[l]
-        in_specs.append(pl.BlockSpec((1, m.c_out_pad),
-                                     lambda b_, t: (0, 0)))
+    def const(*shape):
+        return pl.BlockSpec(shape, lambda c, b_, t: (0,) * len(shape))
 
+    x_block = (chunk, p["x0_rows"], first.padded_in_w, first.c_in_pad)
+    in_specs = [const(*x_block) if chunk_b is None else
+                pl.BlockSpec(x_block, lambda c, b_, t: (c, 0, 0, 0))]
+    in_specs += [const(m.kernel, m.kernel, m.c_in_pad, m.c_out_pad)
+                 for m in plan.layers]
+    in_specs += [const(1, m.c_out_pad) for m in plan.layers]
     args = [p["xp"], *p["ws"], *p["bs"]]
-    out_specs = [pl.BlockSpec((1, tile_h, last.out_w, last.c_out_pad),
-                              lambda b_, t: (b_, t, 0, 0))]
+    out_specs = [pl.BlockSpec(
+        (1, tile_h, last.out_w, last.c_out_pad),
+        lambda c, b_, t: (c * chunk + b_, t, 0, 0))]
     out_shape = [jax.ShapeDtypeStruct(
-        (B, n_tiles * tile_h, last.out_w, last.c_out_pad), x.dtype)]
+        (b_pad, n_tiles * tile_h, last.out_w, last.c_out_pad), x.dtype)]
     if p["has_head"]:
         d_pad = p["d_pad"]
-        in_specs.append(pl.BlockSpec((n_tiles, p["tile_flat"], d_pad),
-                                     lambda b_, t: (0, 0, 0)))
-        in_specs.append(pl.BlockSpec((1, d_pad), lambda b_, t: (0, 0)))
+        in_specs += [const(p["head_rows"], last.out_w, d_pad),
+                     const(1, d_pad)]
         args += [p["hw_pad"], p["hb"]]
-        out_specs.append(pl.BlockSpec((1, d_pad), lambda b_, t: (b_, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((B, d_pad), x.dtype))
+        out_specs.append(pl.BlockSpec(
+            (1, 1, d_pad), lambda c, b_, t: (c * chunk + b_, 0, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((b_pad, 1, d_pad), x.dtype))
 
     out = pl.pallas_call(
         functools.partial(_encoder_kernel, plan=plan, tile_h=tile_h,
-                          scratch_rows=p["scratch_rows"],
                           has_head=p["has_head"], head_act=head_act),
-        grid=(B, n_tiles),
+        grid=(n_chunks, chunk, n_tiles),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=p["scratch_shapes"],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "arbitrary")),
+        # the VMEM limit is the budget the PassPlan's residency model
+        # checks against, so plan and compiler agree on what fits
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "parallel", "arbitrary"),
+            vmem_limit_bytes=DEFAULT_VMEM_LIMIT),
         interpret=interpret,
     )(*args)
-    feats = out[0][:, :plan.out_h, :, :plan.k_out]
-    return (feats, out[1][:, :p["d_out"]]) if p["has_head"] else feats
+    feats = out[0][:B, :plan.out_h, :, :plan.k_out]
+    if p["has_head"]:
+        return feats, out[1][:B, 0, :p["d_out"]]
+    return feats
 
 
 # ---------------------------------------------------------------------------
 # Tier 4: large-batch streaming (the batch no longer has to fit VMEM)
 # ---------------------------------------------------------------------------
-
-@functools.partial(jax.jit, static_argnames=("plan", "chunk_b", "tile_h",
-                                             "head_act", "interpret"))
-def _miniconv_encoder_pipelined(x, weights, biases, plan, *, chunk_b: int,
-                                tile_h: int, head_w, head_b, head_act: str,
-                                interpret: bool):
-    """ONE pallas_call over a (n_chunks, chunk_b, n_tiles) grid.
-
-    The input BlockSpec covers one ``chunk_b``-frame chunk and its index
-    map advances with the chunk grid dimension, so only one chunk's input
-    block is VMEM-resident at a time; on compiled TPU, Pallas's revolving
-    block buffers fetch chunk c+1 HBM->VMEM while chunk c computes (the
-    double-buffered pipeline).  The batch is zero-padded up to a whole
-    number of chunks; padded frames compute garbage that is sliced off
-    (each batch element is independent, so real frames are bitwise
-    unaffected).
-    """
-    B = x.shape[0]
-    n_chunks = -(-B // chunk_b)
-    b_pad = n_chunks * chunk_b
-    if b_pad != B:
-        x = jnp.pad(x, ((0, b_pad - B), (0, 0), (0, 0), (0, 0)))
-    p = _prep_fused_inputs(x, weights, biases, plan, tile_h=tile_h,
-                           head_w=head_w, head_b=head_b)
-    L, first, last = p["L"], p["first"], p["last"]
-    tile_h, n_tiles = p["tile_h"], p["n_tiles"]
-
-    # Per-chunk input block: fetched when the chunk index advances.
-    in_specs = [pl.BlockSpec(
-        (chunk_b, p["x0_rows"], first.padded_in_w, first.c_in_pad),
-        lambda c, b_, t: (c, 0, 0, 0))]
-    for l in range(L):
-        m = plan.layers[l]
-        in_specs.append(pl.BlockSpec(
-            (m.kernel, m.kernel, m.c_in_pad, m.c_out_pad),
-            lambda c, b_, t: (0, 0, 0, 0)))
-    for l in range(L):
-        m = plan.layers[l]
-        in_specs.append(pl.BlockSpec((1, m.c_out_pad),
-                                     lambda c, b_, t: (0, 0)))
-
-    args = [p["xp"], *p["ws"], *p["bs"]]
-    out_specs = [pl.BlockSpec(
-        (1, tile_h, last.out_w, last.c_out_pad),
-        lambda c, b_, t: (c * chunk_b + b_, t, 0, 0))]
-    out_shape = [jax.ShapeDtypeStruct(
-        (b_pad, n_tiles * tile_h, last.out_w, last.c_out_pad), x.dtype)]
-    if p["has_head"]:
-        d_pad = p["d_pad"]
-        in_specs.append(pl.BlockSpec((n_tiles, p["tile_flat"], d_pad),
-                                     lambda c, b_, t: (0, 0, 0)))
-        in_specs.append(pl.BlockSpec((1, d_pad), lambda c, b_, t: (0, 0)))
-        args += [p["hw_pad"], p["hb"]]
-        out_specs.append(pl.BlockSpec((1, d_pad),
-                                      lambda c, b_, t: (c * chunk_b + b_, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((b_pad, d_pad), x.dtype))
-
-    out = pl.pallas_call(
-        functools.partial(_encoder_kernel, plan=plan, tile_h=tile_h,
-                          scratch_rows=p["scratch_rows"],
-                          has_head=p["has_head"], head_act=head_act,
-                          streamed=True),
-        grid=(n_chunks, chunk_b, n_tiles),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=p["scratch_shapes"],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("arbitrary", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(*args)
-    feats = out[0][:B, :plan.out_h, :, :plan.k_out]
-    return (feats, out[1][:B, :p["d_out"]]) if p["has_head"] else feats
-
 
 def miniconv_encoder_stream(x, weights, biases, plan, *, chunk_b: int,
                             tile_h: int = 8, head_w=None, head_b=None,
@@ -652,53 +576,43 @@ def miniconv_encoder_stream(x, weights, biases, plan, *, chunk_b: int,
 
     Splits the (B, H, W, C) batch into ``chunk_b``-frame chunks so only one
     chunk's input is VMEM-resident at a time (``chunk_b`` should come from
-    ``PassPlan.max_safe_batch``).  Two execution strategies:
+    ``PassPlan.max_safe_batch(streamed=True)``).  Two execution strategies:
 
     * ``pipelined=True`` — ONE pallas_call whose grid iterates chunks;
-      per-chunk input BlockSpecs give the double-buffered HBM->VMEM fetch
-      on compiled TPU.  Default on compiled TPU.  Bitwise equal to the
-      single whole-batch fused launch.
-    * ``pipelined=False`` — automatic multi-launch splitting: one fused
-      launch per chunk (at most two compiled programs: the full chunk and
-      the remainder).  The portable fallback; default everywhere else
-      (per-step block fetches are pathologically slow in interpret mode).
-      Bitwise equal to running :func:`miniconv_encoder` chunk-by-chunk and
+      per-chunk input BlockSpecs give the double-buffered HBM->VMEM fetch.
+      The default for compiled kernels.  Bitwise equal to the single
+      whole-batch fused launch.
+    * ``pipelined=False`` — one fused launch per chunk (at most two
+      compiled programs: the full chunk and the remainder).  The default
+      in interpret mode, where per-step block fetches are slow.  Bitwise
+      equal to running :func:`miniconv_encoder` chunk-by-chunk and
       concatenating — by construction.
 
     When ``B % chunk_b == 0`` the two strategies are themselves bitwise
-    identical (every chunk launch has the same grid shape as the streamed
-    grid's inner steps).  A ragged remainder chunk may differ from the
-    whole-batch launch by float-associativity ulps in the head projection
-    (XLA schedules a size-1 grid differently); features are always
-    bitwise.
+    identical (every chunk launch runs the same kernel body as the
+    streamed grid's inner steps).
 
     Returns the same (features[, projection]) as :func:`miniconv_encoder`.
     """
     if chunk_b < 1:
         raise ValueError(f"chunk_b must be >= 1, got {chunk_b}")
-    if interpret is None:
-        interpret = (not os.environ.get("REPRO_PALLAS_COMPILE")
-                     and jax.default_backend() != "tpu")
+    interpret = resolve_interpret(interpret)
+    kw = dict(tile_h=tile_h, head_b=head_b, head_act=head_act,
+              interpret=interpret)
     B = x.shape[0]
     if B <= chunk_b:                      # fits one launch: nothing to stream
-        return _miniconv_encoder(x, weights, biases, plan, tile_h=tile_h,
-                                 head_w=head_w, head_b=head_b,
-                                 head_act=head_act, interpret=interpret)
+        return _fused_launch(x, weights, biases, plan, head_w=head_w, **kw)
     if pipelined is None:
-        pipelined = not interpret and jax.default_backend() == "tpu"
+        pipelined = not interpret
     if pipelined:
-        return _miniconv_encoder_pipelined(
-            x, weights, biases, plan, chunk_b=chunk_b, tile_h=tile_h,
-            head_w=head_w, head_b=head_b, head_act=head_act,
-            interpret=interpret)
-    # Multi-launch splitting: tile the head ONCE (not per chunk).
+        return _fused_launch(x, weights, biases, plan, head_w=head_w,
+                             chunk_b=chunk_b, **kw)
+    # One launch per chunk: tile the head ONCE (not per chunk).
     if head_w is not None and head_w.ndim == 2:
         head_w = prepare_fused_head(head_w, plan, tile_h=tile_h)
-    chunks = [
-        _miniconv_encoder(x[i:i + chunk_b], weights, biases, plan,
-                          tile_h=tile_h, head_w=head_w, head_b=head_b,
-                          head_act=head_act, interpret=interpret)
-        for i in range(0, B, chunk_b)]
+    chunks = [_fused_launch(x[i:i + chunk_b], weights, biases, plan,
+                            head_w=head_w, **kw)
+              for i in range(0, B, chunk_b)]
     if head_w is not None:
         return (jnp.concatenate([c[0] for c in chunks]),
                 jnp.concatenate([c[1] for c in chunks]))

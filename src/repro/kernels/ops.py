@@ -1,28 +1,20 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-On this CPU container the kernels run in interpret mode (the kernel body is
-executed in Python for correctness); on TPU set ``REPRO_PALLAS_COMPILE=1``
-or pass interpret=False explicitly.
+``interpret=None`` resolves through
+:func:`repro.kernels.interpret.resolve_interpret`: interpreted on the CPU
+backend, compiled on the chip.
 """
 from __future__ import annotations
 
-import functools
-import os
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 
 from repro.kernels.flash_attention import flash_attention
+from repro.kernels.interpret import resolve_interpret
 from repro.kernels.miniconv_pass import (miniconv_encoder,
                                          miniconv_layer_grouped,
                                          miniconv_pass)
-
-
-def _default_interpret() -> bool:
-    if os.environ.get("REPRO_PALLAS_COMPILE"):
-        return False
-    return jax.default_backend() != "tpu"
 
 
 def same_pad(x, kernel: int, stride: int):
@@ -63,7 +55,7 @@ def miniconv_layer(x, kernel, bias, *, stride: int = 1,
     pallas_call (output-group as a grid dimension); the default runs one
     pallas_call per pass — the legacy reference path.
     """
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     kh = kernel.shape[0]
     kernel, bias, c_out = _pad_groups(kernel, bias)
     xp = same_pad(x, kh, stride)
@@ -82,7 +74,7 @@ def causal_attention(q, k, v, *, sliding_window: Optional[int] = None,
                      block_q: int = 128, block_k: int = 128,
                      interpret: Optional[bool] = None):
     """(B, H, S, D) flash attention wrapper (causal)."""
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     return flash_attention(q, k, v, causal=True,
                            sliding_window=sliding_window,
                            block_q=block_q, block_k=block_k,

@@ -65,16 +65,8 @@ _ELEMENTWISE = {"add", "subtract", "multiply", "divide", "power", "negate",
 
 
 def flat_cost_analysis(compiled) -> dict:
-    """XLA's flat per-module cost analysis as ONE dict.
-
-    ``Compiled.cost_analysis()`` returns a dict on current jax but a
-    one-element list of dicts on older releases (0.4.x); normalise so
-    callers (and the validation tests) can index properties directly.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
+    """XLA's flat per-module cost analysis (each ``while`` body once)."""
+    return dict(compiled.cost_analysis())
 
 
 def _elems(dims: str) -> int:

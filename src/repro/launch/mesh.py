@@ -11,17 +11,8 @@ import math
 import jax
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """``axis_types`` kwarg for ``jax.make_mesh`` when this jax has it.
-
-    ``jax.sharding.AxisType`` (and the matching ``make_mesh`` kwarg)
-    landed after 0.4.37; on the pinned jax every mesh axis is already
-    Auto by default, so omitting the kwarg is behaviour-identical.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+def _auto_axes(n_axes: int) -> tuple:
+    return (jax.sharding.AxisType.Auto,) * n_axes
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> "jax.sharding.Mesh":
@@ -37,12 +28,12 @@ def make_production_mesh(*, multi_pod: bool = False) -> "jax.sharding.Mesh":
             "XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
             "importing jax")
     return jax.make_mesh(
-        shape, axes, devices=devices[:n], **_axis_type_kwargs(len(axes)))
+        shape, axes, devices=devices[:n], axis_types=_auto_axes(len(axes)))
 
 
 def make_host_mesh(shape=(1, 1), axes=("data", "model")) -> \
         "jax.sharding.Mesh":
     """Tiny mesh over whatever devices exist (tests / smoke runs)."""
     n = math.prod(shape)
-    return jax.make_mesh(
-        shape, axes, devices=jax.devices()[:n], **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, devices=jax.devices()[:n],
+                         axis_types=_auto_axes(len(axes)))

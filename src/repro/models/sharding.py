@@ -33,11 +33,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.nn.module import tree_paths
 
-# jax.tree.map_with_path is absent before jax 0.5; the tree_util spelling
-# exists on every supported version
-_tree_map_with_path = getattr(jax.tree, "map_with_path",
-                              jax.tree_util.tree_map_with_path)
-
 Axes = tuple[str, ...]            # one axis group, e.g. ("pod", "data")
 DimPrefs = Sequence[Axes]         # candidates for one dim, in pref. order
 Rule = Sequence[DimPrefs]         # one entry per *logical* dim of the leaf
@@ -140,7 +135,7 @@ def param_shardings(param_shapes: Any, mesh: Mesh, *,
     flat = dict(tree_paths(param_shapes))
     specs = {p: param_spec(p, v.shape, mesh, mode=mode)
              for p, v in flat.items()}
-    return _tree_map_with_path(
+    return jax.tree.map_with_path(
         lambda kp, v: NamedSharding(mesh, specs[_path_str(kp)]),
         param_shapes)
 
@@ -230,7 +225,7 @@ def cache_spec(path: str, shape: Sequence[int], mesh: Mesh,
 def cache_shardings(cache_shapes: Any, mesh: Mesh, batch: int) -> Any:
     flat = dict(tree_paths(cache_shapes))
     specs = {p: cache_spec(p, v.shape, mesh, batch) for p, v in flat.items()}
-    return _tree_map_with_path(
+    return jax.tree.map_with_path(
         lambda kp, v: NamedSharding(mesh, specs[_path_str(kp)]),
         cache_shapes)
 

@@ -21,14 +21,10 @@ from typing import Optional
 
 
 def execution_mode(interpret: Optional[bool] = None) -> str:
-    """``"interpret"`` or ``"compiled"`` — resolved exactly like the
-    kernel layer resolves ``interpret=None`` (compiled on TPU or with
-    ``REPRO_PALLAS_COMPILE=1``, interpret everywhere else)."""
-    if interpret is None:
-        import jax
-        interpret = (not os.environ.get("REPRO_PALLAS_COMPILE")
-                     and jax.default_backend() != "tpu")
-    return "interpret" if interpret else "compiled"
+    """``"interpret"`` or ``"compiled"`` — resolved by the kernel layer's
+    own switch (:func:`repro.kernels.interpret.resolve_interpret`)."""
+    from repro.kernels.interpret import resolve_interpret
+    return "interpret" if resolve_interpret(interpret) else "compiled"
 
 
 def host_fingerprint() -> str:

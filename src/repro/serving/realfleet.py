@@ -1,4 +1,4 @@
-"""Real multi-process serving fleet behind the router registry.
+"""Real serving fleet behind the router registry.
 
 Everything fleet-shaped before this module was simulation:
 :class:`~repro.serving.fleet.FleetQueueSim` *predicts* what ``n_servers``
@@ -11,20 +11,23 @@ wall-clock measurements (the DistrEdge-style sim-to-real calibration in
   TCP listener whose admission loop does CONTINUOUS batching (admit every
   request that arrived while the previous micro-batch was in service, up
   to ``max_batch`` — no fixed ``max_wait_ms`` hold; the running batch's
-  service time IS the batching window).  Runs in-process for tests, or as
-  the body of a spawned worker process (:func:`_worker_main`, which
-  rebuilds the jitted server half from the deployment manifest — compiled
-  functions cannot cross a process boundary).
+  service time IS the batching window).  Runs in-process, or as the body
+  of a spawned worker process (:func:`_worker_main`); either way the
+  jitted server half is rebuilt from the deployment manifest
+  (:func:`_build_worker` — compiled functions cannot cross a process
+  boundary).
 * :class:`FleetClient` — the front door: one socket per worker, requests
   routed by the SAME registered policies the simulator uses
   (``repro.serving.fleet.ROUTERS``), with per-request timeouts and
   bounded retries that re-route around dead or stalled workers.
-* :class:`RealFleet` — the process manager: spawns ``n_servers`` worker
-  processes from one deployment manifest + parameter pytree, wires up a
+* :class:`RealFleet` — the replica manager: starts ``n_servers`` workers
+  from one deployment manifest + parameter pytree, wires up a
   :class:`FleetClient`, and on :meth:`RealFleet.close` drains in-flight
-  requests (graceful SHUTDOWN frame) before joining — returning the PIDs
-  of any worker that had to be killed, so CI can gate on "no leaked
-  workers".
+  requests (graceful SHUTDOWN frame) before joining — returning any
+  worker that had to be killed, so CI can gate on "no leaked workers".
+  On the CPU each worker is a spawned process; on an accelerator, where
+  one process holds the chips, every worker runs in the calling process
+  with its parameters and jitted server half on its own device.
 * :func:`run_load` — the open-loop load generator (N clients at a fixed
   decision rate, the Table 6 protocol) whose latency sample feeds the
   measured-vs-predicted p95 calibration.
@@ -48,6 +51,7 @@ import threading
 import time
 from typing import Callable, Optional, Sequence, Union
 
+import jax
 import numpy as np
 
 from repro.schema import check_version
@@ -275,6 +279,7 @@ class WorkerServer:
         self._conns: list[socket.socket] = []
         self.n_served = 0
         self.batch_sizes: list[int] = []
+        self.devices: set = set()      # devices the served actions lived on
         self.addr: Optional[tuple[str, int]] = None
 
     # ---- lifecycle ---------------------------------------------------------
@@ -296,6 +301,10 @@ class WorkerServer:
     def join(self, timeout: Optional[float] = None) -> None:
         """Block until the serve loop exits (graceful drain or stop)."""
         self._serve_t.join(timeout)
+
+    def is_alive(self) -> bool:
+        """Whether the serve loop is still running."""
+        return self._serve_t.is_alive()
 
     def stop(self) -> None:
         """Hard stop: abort the loop and drop every connection (used by
@@ -398,7 +407,10 @@ class WorkerServer:
         stacked = {k: np.stack([r.payload[k] for r in batch])
                    for k in batch[0].payload}
         try:
-            out = np.asarray(self.serve_batch_fn(stacked))
+            out = self.serve_batch_fn(stacked)
+            if isinstance(out, jax.Array):
+                self.devices |= out.devices()
+            out = np.asarray(out)
         except Exception as e:  # repro: allow(broad-except) -- serve_batch_fn is arbitrary user code; answer MSG_ERR rather than hang the clients
             msg = f"{type(e).__name__}: {e}".encode()[:2000]
             for r in batch:
@@ -415,22 +427,28 @@ class WorkerServer:
         self.batch_sizes.append(len(batch))
 
 
-def _worker_main(manifest: dict, params, max_batch: int, conn,
-                 precompile: bool = True,
-                 shaping: Optional[dict] = None) -> None:
-    """Entry point of one spawned worker process.
+def _build_worker(manifest: dict, params, max_batch: int,
+                  precompile: bool = True, shaping: Optional[dict] = None,
+                  device=None) -> WorkerServer:
+    """One (unstarted) worker for a deployment manifest.
 
-    Rebuilds the jitted server half from the deployment manifest (jitted
-    callables cannot cross a process boundary; the manifest + numpy
-    parameter pytree can), optionally pre-compiles every admissible batch
-    shape so the first live micro-batches are not compile-skewed, then
-    reports its bound (host, port) through ``conn`` and serves until a
-    SHUTDOWN frame drains it.
+    Rebuilds the jitted server half from the manifest (jitted callables
+    cannot cross a process boundary; the manifest + numpy parameter
+    pytree can) and optionally pre-compiles every admissible batch shape
+    so the first live micro-batches are not compile-skewed.  With a
+    ``device``, the parameters and every micro-batch are placed on it, so
+    the server half runs there.
     """
-    from repro.deploy import Deployment, DeploymentConfig  # noqa: import in child
+    from repro.deploy import Deployment, DeploymentConfig  # lazy: cycle
     cfg = DeploymentConfig.from_dict(manifest)
     dep = Deployment.build(cfg)
-    serve = dep.server_batch_fn(params)
+    if device is None:
+        serve = dep.server_batch_fn(params)
+    else:
+        on_device = dep.server_batch_fn(jax.device_put(params, device))
+
+        def serve(stacked):
+            return on_device(jax.device_put(stacked, device))
     if precompile:
         edge = dep.split.edge_step(
             Deployment._split_params(params)["edge"],
@@ -444,7 +462,16 @@ def _worker_main(manifest: dict, params, max_batch: int, conn,
                               for k, v in example.items()}))
     shaper = (ShapingConfig.from_dict(shaping).bucket()
               if shaping is not None else None)
-    ws = WorkerServer(serve, max_batch=max_batch, shaper=shaper)
+    return WorkerServer(serve, max_batch=max_batch, shaper=shaper)
+
+
+def _worker_main(manifest: dict, params, max_batch: int, conn,
+                 precompile: bool = True,
+                 shaping: Optional[dict] = None) -> None:
+    """Entry point of one spawned worker process: build the worker,
+    report its bound (host, port) through ``conn`` and serve until a
+    SHUTDOWN frame drains it."""
+    ws = _build_worker(manifest, params, max_batch, precompile, shaping)
     conn.send(ws.start())
     conn.close()
     ws.join()
@@ -676,17 +703,30 @@ class FleetClient:
 
 
 # ---------------------------------------------------------------------------
-# The process manager
+# The replica manager
 # ---------------------------------------------------------------------------
 
+def _spawns_workers() -> bool:
+    """Workers are spawned processes only on the CPU backend."""
+    return jax.default_backend() == "cpu"
+
+
 class RealFleet:
-    """``n_servers`` spawned worker processes + a routed front door.
+    """``n_servers`` workers + a routed front door.
 
     Built from ONE deployment manifest dict and a numpy parameter pytree
     (both picklable across the spawn boundary; each worker rebuilds its
     jitted server half via ``Deployment.build``).  Use
     :meth:`~repro.deploy.Deployment.fleet` to construct from a built
     deployment, or this class directly with a manifest.
+
+    Where the workers run follows the backend (:func:`_spawns_workers`):
+    on the CPU they are spawned processes (tests kill them to exercise
+    retries); on an accelerator, where one process holds the chips and a
+    spawned child could not reach them, each worker is a
+    :class:`WorkerServer` in the calling process, replica ``i`` on
+    ``jax.devices()[i]`` (cycled when there are more replicas than
+    devices), behind the same :class:`FleetClient` sockets and routers.
     """
 
     def __init__(self, manifest: dict, params, *, n_servers: int = 1,
@@ -709,13 +749,38 @@ class RealFleet:
             shaping = ShapingConfig.from_dict(shaping)
         self.shaping = shaping
         self._mp_context = mp_context
+        self.in_process = not _spawns_workers()
         self.processes: list = []
+        self.workers: list[WorkerServer] = []     # in-process replicas
         self.client: Optional[FleetClient] = None
         self.closed = False
 
     # ---- lifecycle ---------------------------------------------------------
     def start(self, *, start_timeout_s: float = 120.0) -> "RealFleet":
-        """Spawn the workers, collect their ports, connect the client."""
+        """Start the workers, collect their ports, connect the client."""
+        addrs = (self._start_in_process() if self.in_process
+                 else self._spawn(start_timeout_s))
+        self.client = FleetClient(addrs, router=self.router,
+                                  timeout_s=self.timeout_s,
+                                  retries=self.retries)
+        return self
+
+    def _start_in_process(self) -> list[tuple[str, int]]:
+        devices = jax.devices()
+        shaping = None if self.shaping is None else self.shaping.to_dict()
+        try:
+            for i in range(self.n_servers):
+                self.workers.append(_build_worker(
+                    self.manifest, self.params, self.max_batch,
+                    self.precompile, shaping,
+                    device=devices[i % len(devices)]))
+            return [w.start() for w in self.workers]
+        except BaseException:
+            self._kill_all()
+            self.workers.clear()
+            raise
+
+    def _spawn(self, start_timeout_s: float) -> list[tuple[str, int]]:
         import multiprocessing as mp
         ctx = mp.get_context(self._mp_context)
         pipes = []
@@ -755,10 +820,7 @@ class RealFleet:
         except BaseException:
             self._kill_all()
             raise
-        self.client = FleetClient(addrs, router=self.router,
-                                  timeout_s=self.timeout_s,
-                                  retries=self.retries)
-        return self
+        return addrs
 
     def __enter__(self) -> "RealFleet":
         return self if self.client is not None else self.start()
@@ -787,6 +849,9 @@ class RealFleet:
 
     # ---- shutdown ----------------------------------------------------------
     def _kill_all(self) -> None:
+        for w in self.workers:
+            if w.addr is not None:
+                w.stop()
         for p in self.processes:
             if p.is_alive():
                 p.terminate()
@@ -800,8 +865,9 @@ class RealFleet:
     def close(self, *, grace_s: float = 15.0) -> list[int]:
         """Graceful shutdown: drain in-flight requests, join the workers.
 
-        Returns the PIDs of workers that did NOT exit gracefully and had
-        to be terminated — the CI leak gate asserts this is empty.
+        Returns the workers that did NOT exit gracefully and had to be
+        stopped — PIDs of spawned processes, indices of in-process
+        replicas; the CI leak gate asserts this is empty.
         """
         if self.closed:
             return []
@@ -809,9 +875,12 @@ class RealFleet:
         if self.client is not None:
             self.client.shutdown(wait_pending_s=grace_s)
         deadline = time.monotonic() + grace_s
+        for w in self.workers:
+            w.join(timeout=max(0.1, deadline - time.monotonic()))
         for p in self.processes:
             p.join(timeout=max(0.1, deadline - time.monotonic()))
-        leaked = [p.pid for p in self.processes if p.is_alive()]
+        leaked = ([i for i, w in enumerate(self.workers) if w.is_alive()]
+                  + [p.pid for p in self.processes if p.is_alive()])
         self._kill_all()
         return leaked
 

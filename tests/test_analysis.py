@@ -424,8 +424,9 @@ def test_kernel_interpret_negative():
 
 
 def test_vmem_audit_default_budget():
-    # under the real 16 MiB budget only the known 400x400 head-fused
-    # limitation fires (carried in the committed baseline, not fixed)
+    # under the real budget only the known c_in=4 400x400 limitation of
+    # today's channels-on-lanes layout fires (carried in the committed
+    # baseline, not fixed)
     findings = audit_vmem_budgets()
     assert all("400x400" in f.message for f in findings)
 

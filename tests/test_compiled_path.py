@@ -1,14 +1,12 @@
-"""Compiled-path (REPRO_PALLAS_COMPILE=1) validation tier.
+"""Compiled-kernel tier: runs on a TPU only.
 
 Exercises the fused and fused+head kernels COMPILED (interpret=False) at
 the ``max_safe_batch`` VMEM boundary and far past it through the
-``fused+stream`` batch pipeline.  Most CPU-only JAX builds cannot lower a
-non-interpret pallas_call at all ("Only interpret mode is supported on
-CPU backend"), so the whole module skips with an explicit marker unless
-:func:`repro.kernels.pallas_compat.compiled_pallas_supported` probes
-true (TPU hosts, or CPU builds with compiled-Pallas support).  CI runs
-this file under ``REPRO_PALLAS_COMPILE=1``; on its CPU runners the skip
-marker IS the expected outcome.
+``fused+stream`` batch pipeline, against the interpret-mode oracle; and
+checks that the oracle tiers off the main path (``reference`` and
+``grouped``) fail on the chip with the compiler's error instead of running
+interpreted.  Elsewhere every test skips: the backend is checked in a
+fixture, never while the module is imported.
 """
 import jax
 import jax.numpy as jnp
@@ -16,21 +14,20 @@ import numpy as np
 import pytest
 
 from repro.core.miniconv import miniconv_init, standard_spec
-from repro.kernels.pallas_compat import compiled_pallas_supported
 from repro.kernels.miniconv_pass import (miniconv_encoder,
                                          miniconv_encoder_stream)
-
-pytestmark = pytest.mark.skipif(
-    not compiled_pallas_supported(),
-    reason="compiled (non-interpret) Pallas is not supported on this "
-           "host's JAX backend — compiled-path tier requires TPU or a "
-           "compiled-Pallas-capable build")
 
 X = 48          # deployment-scale input, small enough for CI arrays
 
 
 @pytest.fixture(scope="module")
-def fixture():
+def on_tpu():
+    if jax.default_backend() != "tpu":
+        pytest.skip("compiled Pallas kernels need a TPU backend")
+
+
+@pytest.fixture(scope="module")
+def fixture(on_tpu):
     spec = standard_spec()
     params = miniconv_init(jax.random.PRNGKey(0), spec)
     plan = spec.plan(X)
@@ -70,7 +67,7 @@ def test_compiled_stream_past_max_safe(fixture, with_head):
     bitwise-equal to compiled chunk-by-chunk fused execution."""
     plan, ws, bs, hw, hb = fixture
     chunk = min(plan.max_safe_batch(head=plan.head(32) if with_head
-                                    else None), 8)
+                                    else None, streamed=True), 8)
     assert chunk >= 1
     b = 4 * chunk
     kw = dict(head_w=hw, head_b=hb) if with_head else {}
@@ -84,3 +81,18 @@ def test_compiled_stream_past_max_safe(fixture, with_head):
         np.testing.assert_array_equal(pipe[1], multi[1])
     else:
         np.testing.assert_array_equal(pipe, multi)
+
+
+@pytest.mark.parametrize("fused_groups", [False, True])
+def test_oracle_tiers_fail_on_chip_instead_of_interpreting(on_tpu,
+                                                           fused_groups):
+    """The per-pass and grouped oracles resolve to compiled mode on the
+    chip, where their BlockSpecs do not compile: the compiler's error
+    surfaces instead of an interpreted run."""
+    from repro.kernels.ops import miniconv_layer
+    x = jnp.zeros((1, 12, 12, 4))
+    w = jnp.zeros((3, 3, 4, 4))
+    with pytest.raises(Exception):
+        jax.block_until_ready(miniconv_layer(x, w, jnp.zeros((4,)),
+                                             stride=2,
+                                             fused_groups=fused_groups))

@@ -25,7 +25,8 @@ from repro.core.backends import backend_names, get_backend
 from repro.core.miniconv import (LayerSpec, MiniConvSpec, ShaderBudget,
                                  miniconv_apply, miniconv_init,
                                  standard_spec)
-from repro.core.passplan import DEFAULT_VMEM_LIMIT, build_pass_plan
+from repro.core.passplan import (DEFAULT_VMEM_LIMIT, build_pass_plan,
+                                 tiled_bytes)
 from repro.core.split import make_miniconv_split
 from repro.deploy import CONFIG_VERSION, Deployment, DeploymentConfig
 from repro.rl.networks import make_encoder
@@ -229,6 +230,51 @@ def test_vmem_bytes_affine_in_batch():
     assert d1 == d2 > 0
     head = plan.head(512)
     assert plan.vmem_bytes(1, head=head) > plan.vmem_bytes(1)
+    # a streamed chunk's input block changes across the grid, so the
+    # compiler double-buffers it
+    s1 = plan.vmem_bytes(2, streamed=True) - plan.vmem_bytes(1, streamed=True)
+    assert s1 == 2 * d1
+
+
+def test_vmem_input_block_counts_compiler_tiles():
+    """The (B, 86, 86, 12) padded input block of the standard 84x84 plan
+    is laid out in (8, 128) tiles: 86 x 88 x 128 x 4 bytes per frame, not
+    the 86 x 86 x 12 x 4 it holds."""
+    per_frame = 86 * 88 * 128 * 4
+    assert tiled_bytes((86, 86, 12)) == per_frame
+    assert tiled_bytes((48, 86, 86, 12)) == 48 * per_frame
+    plan = standard_spec(c_in=12, k=4).plan(84)
+    assert plan.vmem_bytes(2) - plan.vmem_bytes(1) == per_frame
+    # sub-32-bit types pack more rows per sublane tile
+    assert tiled_bytes((86, 86, 12), itemsize=2) == 86 * 96 * 128 * 2
+    assert tiled_bytes((5,)) == 8 * 128 * 4
+
+
+def test_standard_deployment_max_safe_batch():
+    """The standard deployment's launchable micro-batches under the
+    compiler-shaped model (tests/test_tpu_compile.py compiles the fused
+    launch at exactly these sizes)."""
+    plan = standard_spec(c_in=12, k=4).plan(84)
+    head = plan.head(512)
+    assert plan.max_safe_batch() == 25
+    assert plan.max_safe_batch(head=head) == 24
+    assert plan.max_safe_batch(head=head, streamed=True) == 12
+    dep = Deployment.build(DeploymentConfig.standard(
+        k=4, c_in=12, h=84, backend="fused+stream", interpret=False,
+        max_batch=64))
+    assert dep.max_safe_batch == 24 and dep.stream_chunk == 12
+
+
+def test_unlaunchable_when_one_frame_exceeds_vmem():
+    """c_in=4 at 400x400: one tile-padded frame plus the intermediates
+    exceeds the budget, so a compiled fused build has no launchable batch."""
+    plan = standard_spec(c_in=4, k=4).plan(400)
+    assert plan.max_safe_batch() == 0
+    assert plan.vmem_bytes(1) > DEFAULT_VMEM_LIMIT
+    cfg = DeploymentConfig.standard(k=4, c_in=4, h=400, backend="fused",
+                                    interpret=False)
+    with pytest.raises(ValueError, match="max_safe_batch=0"):
+        Deployment.build(cfg)
 
 
 def test_build_pass_plan_batch_budget_check():
@@ -249,6 +295,8 @@ def test_deployment_surfaces_max_safe_batch():
     dep = Deployment.build(SMALL)
     assert dep.max_safe_batch == dep.plan.max_safe_batch(
         tile_h=SMALL.tile_h)
+    assert dep.plan.max_safe_batch(tile_h=SMALL.tile_h, streamed=True) \
+        < dep.max_safe_batch
     # fusing the head consumes VMEM for the tiled weight -> smaller B
     fused_head = Deployment.build(
         dataclasses.replace(SMALL, backend="fused+head"))

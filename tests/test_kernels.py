@@ -89,3 +89,16 @@ def test_same_pad_matches_xla_same():
     ref = conv2d({"kernel": w}, x, stride=2, padding="SAME")
     out = miniconv_pass_ref(xp, w, jnp.zeros((4,)), stride=2)
     np.testing.assert_allclose(out, ref, atol=1e-5, rtol=1e-5)
+
+
+def test_resolve_interpret_only_on_cpu():
+    """One switch: an explicit choice wins; otherwise kernels interpret on
+    the CPU backend only, and perf stamps resolve the same way."""
+    from repro.kernels.interpret import resolve_interpret
+    from repro.perfstamp import execution_mode
+    assert resolve_interpret(True) is True
+    assert resolve_interpret(False) is False
+    on_cpu = jax.default_backend() == "cpu"
+    assert resolve_interpret(None) is on_cpu
+    assert execution_mode() == ("interpret" if on_cpu else "compiled")
+    assert execution_mode(False) == "compiled"

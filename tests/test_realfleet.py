@@ -238,6 +238,41 @@ def test_real_fleet_two_servers_bitwise_and_crash():
     assert leaked == []
 
 
+def test_real_fleet_in_process_replicas_bitwise(monkeypatch):
+    """The accelerator layout, run on the CPU: in-process replicas, each
+    pinned to a device (cycled over the devices there are), serve actions
+    bitwise-equal to in-process serving and stop without leaking."""
+    from repro.deploy import Deployment, DeploymentConfig
+    from repro.serving import realfleet
+    monkeypatch.setattr(realfleet, "_spawns_workers", lambda: False)
+
+    cfg = DeploymentConfig.standard(k=4, c_in=4, h=24, backend="xla",
+                                    max_batch=2, n_servers=2)
+    dep = Deployment.build(cfg)
+    params = dep.init(jax.random.PRNGKey(0))
+    client, server = dep.serving_pair(params)
+    obs = jax.random.uniform(jax.random.PRNGKey(1), (4, 24, 24, 4))
+    payloads = [client.encode_fn(obs[i:i + 1]) for i in range(4)]
+    want = [np.asarray(server.serve([p])[0]) for p in payloads]
+
+    fleet = dep.fleet(params)
+    try:
+        assert fleet.in_process
+        assert fleet.processes == [] and len(fleet.workers) == 2
+        for router in ("round_robin", "client_affinity"):
+            fleet.set_router(router)
+            got = [fleet.request(p, client=i) for i, p in enumerate(payloads)]
+            for w, g in zip(want, got):
+                np.testing.assert_array_equal(w, g)
+        devices = jax.devices()
+        for i, w in enumerate(fleet.workers):
+            assert w.devices == {devices[i % len(devices)]}
+    finally:
+        leaked = fleet.close()
+    assert leaked == []
+    assert not any(w.is_alive() for w in fleet.workers)
+
+
 # ------------------------------------------------------- ingress shaping
 def test_token_bucket_gcra_with_injected_clock():
     from repro.serving.realfleet import TokenBucket

@@ -159,7 +159,8 @@ def test_pruning_drops_vmem_infeasible_compiled_candidates():
     safe = plan.max_safe_batch(tile_h=2)
     over = Candidate(backend="fused", tile_h=2, micro_batch=safe + 1)
     assert not vmem_feasible(cfg, over, compiled=True)
-    # streamed backend only needs ONE frame to fit
+    # streamed backend only needs ONE (double-buffered) frame to fit
+    assert plan.max_safe_batch(tile_h=2, streamed=True) >= 1
     streamed = Candidate(backend="fused+stream", tile_h=2,
                          micro_batch=safe + 1)
     assert vmem_feasible(cfg, streamed, compiled=True)
