@@ -19,6 +19,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro.tracing import scope
+
 Payload = dict[str, jnp.ndarray]
 
 
@@ -34,7 +36,8 @@ class WireCodec:
         return {"data": x.astype(jnp.float32)}
 
     def decode(self, payload: Payload, dtype=jnp.float32) -> jnp.ndarray:
-        return payload["data"].astype(dtype)
+        with scope("wire.decode"):
+            return payload["data"].astype(dtype)
 
     def wire_bytes(self, shape: tuple) -> int:
         return math.prod(shape) * int(self.itemsize) + \
@@ -72,7 +75,8 @@ class BF16Codec(WireCodec):
         return {"data": x.astype(jnp.bfloat16)}
 
     def decode(self, payload, dtype=jnp.float32):
-        return payload["data"].astype(dtype)
+        with scope("wire.decode"):
+            return payload["data"].astype(dtype)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -93,8 +97,9 @@ class Uint8AffineCodec(WireCodec):
         return {"data": q, "scale": scale, "zero": lo}
 
     def decode(self, payload, dtype=jnp.float32):
-        return (payload["data"].astype(jnp.float32) * payload["scale"]
-                + payload["zero"]).astype(dtype)
+        with scope("wire.decode"):
+            return (payload["data"].astype(jnp.float32) * payload["scale"]
+                    + payload["zero"]).astype(dtype)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -114,8 +119,9 @@ class Int8ChannelCodec(WireCodec):
         return {"data": q, "scale": scale}
 
     def decode(self, payload, dtype=jnp.float32):
-        return (payload["data"].astype(jnp.float32)
-                * payload["scale"]).astype(dtype)
+        with scope("wire.decode"):
+            return (payload["data"].astype(jnp.float32)
+                    * payload["scale"]).astype(dtype)
 
     def wire_bytes(self, shape):
         return math.prod(shape) + 4 * shape[-1]

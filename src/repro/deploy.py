@@ -67,6 +67,7 @@ from repro.rl.networks import Encoder, miniconv_encoder_init
 from repro.serving.client import EdgeClient
 from repro.serving.fleet import ROUTERS, FleetQueueSim
 from repro.serving.server import BatchingPolicyServer
+from repro.tracing import scope
 
 # version 2 added the optional ``tuning`` block (a frozen TunedPlan);
 # version-1 manifests load unchanged with ``tuning=None``.
@@ -342,8 +343,10 @@ class Deployment:
                                   stream_chunk=stream_chunk)
 
         def server_apply(server_params, feats):
-            z = dense(server_params["proj"], feats.reshape(feats.shape[0], -1))
-            return _ACTS[head_act](z)
+            with scope("split.project"):
+                z = dense(server_params["proj"],
+                          feats.reshape(feats.shape[0], -1))
+                return _ACTS[head_act](z)
 
         split = SplitModel(edge_apply=edge_apply, server_apply=server_apply,
                            codec=codec,
@@ -362,12 +365,12 @@ class Deployment:
                 p = plan if (mode == "fused"
                              and obs.shape[1:3] == (plan.in_h, plan.in_w)) \
                     else None
-                _, z = miniconv_apply(params["edge"], spec, obs,
-                                      use_kernel=mode, plan=p, tile_h=tile_h,
-                                      head=params["server"]["proj"],
-                                      head_act=head_act, interpret=interpret,
-                                      stream_chunk=stream_chunk
-                                      if p is not None else None)
+                with scope("miniconv.encode"):
+                    _, z = miniconv_apply(
+                        params["edge"], spec, obs, use_kernel=mode, plan=p,
+                        tile_h=tile_h, head=params["server"]["proj"],
+                        head_act=head_act, interpret=interpret,
+                        stream_chunk=stream_chunk if p is not None else None)
                 return z
         else:
             def encoder_apply(params, obs):
@@ -376,12 +379,12 @@ class Deployment:
                 p = plan if (mode == "fused"
                              and obs.shape[1:3] == (plan.in_h, plan.in_w)) \
                     else None
-                feats = miniconv_apply(params["edge"], spec, obs,
-                                       use_kernel=mode, plan=p,
-                                       tile_h=tile_h, interpret=interpret,
-                                       stream_chunk=stream_chunk
-                                       if p is not None else None)
-                return server_apply(params["server"], feats)
+                with scope("miniconv.encode"):
+                    feats = miniconv_apply(
+                        params["edge"], spec, obs, use_kernel=mode, plan=p,
+                        tile_h=tile_h, interpret=interpret,
+                        stream_chunk=stream_chunk if p is not None else None)
+                    return server_apply(params["server"], feats)
 
         encoder = Encoder(name=f"miniconv{spec.k_out}", init=init,
                           apply=encoder_apply, spec=spec)

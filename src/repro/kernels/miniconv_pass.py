@@ -83,6 +83,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.miniconv import _ACTS
 from repro.core.passplan import DEFAULT_VMEM_LIMIT
 from repro.kernels.interpret import resolve_interpret
+from repro.tracing import scope
 
 
 # ---------------------------------------------------------------------------
@@ -387,7 +388,8 @@ def prepare_fused_head(head_w, plan, *, tile_h: int = 8):
     call this ONCE per head and pass the 3-D result instead."""
     tile_h = max(1, min(tile_h, plan.out_h))
     n_tiles = -(-plan.out_h // tile_h)
-    return _tile_head(head_w, plan, rows=n_tiles * tile_h)
+    with scope("miniconv.head_tile"):
+        return _tile_head(head_w, plan, rows=n_tiles * tile_h)
 
 
 def miniconv_encoder(x, weights, biases, plan, *, tile_h: int = 8,
@@ -439,42 +441,47 @@ def _prep_fused_inputs(x, weights, biases, plan, *, tile_h: int,
     # Zero-pad channels to RGBA multiples and bake in layer-0 SAME padding.
     first = layers[0]
     x0_rows = scratch_rows if L == 1 else first.padded_in_h
-    xp = jnp.zeros((B, x0_rows, first.padded_in_w, first.c_in_pad), x.dtype)
-    xp = jax.lax.dynamic_update_slice(
-        xp, x, (0, first.pad_top, first.pad_left, 0))
+    with scope("miniconv.input"):
+        xp = jnp.zeros((B, x0_rows, first.padded_in_w, first.c_in_pad),
+                       x.dtype)
+        xp = jax.lax.dynamic_update_slice(
+            xp, x, (0, first.pad_top, first.pad_left, 0))
     ws, bs = [], []
-    for l, (wt, bi) in enumerate(zip(weights, biases)):
-        m = layers[l]
-        wp = jnp.zeros((m.kernel, m.kernel, m.c_in_pad, m.c_out_pad),
-                       wt.dtype)
-        wp = jax.lax.dynamic_update_slice(wp, wt, (0, 0, 0, 0))
-        bp = jnp.zeros((1, m.c_out_pad), bi.dtype)
-        bp = jax.lax.dynamic_update_slice(bp, bi[None], (0, 0))
-        ws.append(wp)
-        bs.append(bp)
+    with scope("miniconv.weights"):
+        for l, (wt, bi) in enumerate(zip(weights, biases)):
+            m = layers[l]
+            wp = jnp.zeros((m.kernel, m.kernel, m.c_in_pad, m.c_out_pad),
+                           wt.dtype)
+            wp = jax.lax.dynamic_update_slice(wp, wt, (0, 0, 0, 0))
+            bp = jnp.zeros((1, m.c_out_pad), bi.dtype)
+            bp = jax.lax.dynamic_update_slice(bp, bi[None], (0, 0))
+            ws.append(wp)
+            bs.append(bp)
 
     hw_pad = hb = None
     d_out = d_pad = 0
     head_rows = n_tiles * tile_h * last.c_out_pad
     if has_head:
-        if head_w.ndim == 3:              # pre-tiled by prepare_fused_head
-            assert head_w.shape[:2] == (head_rows, last.out_w), \
-                (head_w.shape, head_rows, last.out_w)
-            hw_pad = head_w
-        else:
-            hw_pad = _tile_head(head_w, plan, rows=n_tiles * tile_h)
-        # Lane-pad the projection width to a multiple of 128 so the
-        # epilogue fills whole vector lanes (D=512 is already aligned;
-        # ragged widths gain zero columns that are sliced off below).
-        d_out = hw_pad.shape[-1]
-        d_pad = -(-d_out // 128) * 128
-        if d_pad != d_out:
-            hw_pad = jnp.pad(hw_pad, ((0, 0), (0, 0), (0, d_pad - d_out)))
-        hb = (jnp.zeros((d_out,), hw_pad.dtype) if head_b is None
-              else head_b)
-        if d_pad != d_out:
-            hb = jnp.pad(hb, ((0, d_pad - d_out),))
-        hb = hb.reshape(1, d_pad)
+        with scope("miniconv.head_tile"):
+            if head_w.ndim == 3:          # pre-tiled by prepare_fused_head
+                assert head_w.shape[:2] == (head_rows, last.out_w), \
+                    (head_w.shape, head_rows, last.out_w)
+                hw_pad = head_w
+            else:
+                hw_pad = _tile_head(head_w, plan, rows=n_tiles * tile_h)
+            # Lane-pad the projection width to a multiple of 128 so the
+            # epilogue fills whole vector lanes (D=512 is already aligned;
+            # ragged widths gain zero columns that are sliced off below).
+            d_out = hw_pad.shape[-1]
+            d_pad = -(-d_out // 128) * 128
+            if d_pad != d_out:
+                hw_pad = jnp.pad(hw_pad,
+                                 ((0, 0), (0, 0), (0, d_pad - d_out)))
+            hb = (jnp.zeros((d_out,), hw_pad.dtype) if head_b is None
+                  else head_b)
+            if d_pad != d_out:
+                hb = jnp.pad(hb, ((0, d_pad - d_out),))
+            hb = hb.reshape(1, d_pad)
 
     # SAME-padded inputs of layers 1..L-1; the last is over-allocated to
     # scratch_rows so every tile's reads stay in bounds.
@@ -513,7 +520,8 @@ def _fused_launch(x, weights, biases, plan, *, tile_h: int, head_w, head_b,
     n_chunks = -(-B // chunk)
     b_pad = n_chunks * chunk
     if b_pad != B:
-        x = jnp.pad(x, ((0, b_pad - B), (0, 0), (0, 0), (0, 0)))
+        with scope("miniconv.input"):
+            x = jnp.pad(x, ((0, b_pad - B), (0, 0), (0, 0), (0, 0)))
     p = _prep_fused_inputs(x, weights, biases, plan, tile_h=tile_h,
                            head_w=head_w, head_b=head_b)
     first, last = p["first"], p["last"]
@@ -543,25 +551,27 @@ def _fused_launch(x, weights, biases, plan, *, tile_h: int, head_w, head_b,
             (1, 1, d_pad), lambda c, b_, t: (c * chunk + b_, 0, 0)))
         out_shape.append(jax.ShapeDtypeStruct((b_pad, 1, d_pad), x.dtype))
 
-    out = pl.pallas_call(
-        functools.partial(_encoder_kernel, plan=plan, tile_h=tile_h,
-                          has_head=p["has_head"], head_act=head_act),
-        grid=(n_chunks, chunk, n_tiles),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=p["scratch_shapes"],
-        # the VMEM limit is the budget the PassPlan's residency model
-        # checks against, so plan and compiler agree on what fits
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "parallel", "arbitrary"),
-            vmem_limit_bytes=DEFAULT_VMEM_LIMIT),
-        interpret=interpret,
-    )(*args)
-    feats = out[0][:B, :plan.out_h, :, :plan.k_out]
-    if p["has_head"]:
-        return feats, out[1][:B, 0, :p["d_out"]]
-    return feats
+    with scope("miniconv.kernel"):
+        out = pl.pallas_call(
+            functools.partial(_encoder_kernel, plan=plan, tile_h=tile_h,
+                              has_head=p["has_head"], head_act=head_act),
+            grid=(n_chunks, chunk, n_tiles),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            out_shape=out_shape,
+            scratch_shapes=p["scratch_shapes"],
+            # the VMEM limit is the budget the PassPlan's residency model
+            # checks against, so plan and compiler agree on what fits
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "parallel", "arbitrary"),
+                vmem_limit_bytes=DEFAULT_VMEM_LIMIT),
+            interpret=interpret,
+        )(*args)
+    with scope("miniconv.out"):
+        feats = out[0][:B, :plan.out_h, :, :plan.k_out]
+        if p["has_head"]:
+            return feats, out[1][:B, 0, :p["d_out"]]
+        return feats
 
 
 # ---------------------------------------------------------------------------

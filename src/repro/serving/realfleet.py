@@ -56,6 +56,7 @@ import numpy as np
 
 from repro.schema import check_version
 from repro.serving.fleet import Router, get_router
+from repro.tracing import span
 
 SHAPING_VERSION = 1
 
@@ -241,6 +242,7 @@ class _Request:
     lock: threading.Lock
     req_id: int
     payload: dict
+    arrived: float             # time.monotonic() when the reader queued it
 
 
 class WorkerServer:
@@ -357,7 +359,8 @@ class WorkerServer:
                         time.sleep(wait)
                 (req_id,) = struct.unpack_from("!I", body)
                 self._q.put(_Request(conn, lock, req_id,
-                                     unpack_payload(body[4:])))
+                                     unpack_payload(body[4:]),
+                                     time.monotonic()))
 
     # ---- the continuous-batching admission loop ----------------------------
     def _admit(self) -> Optional[list[_Request]]:
@@ -382,15 +385,16 @@ class WorkerServer:
                 self._draining = True
                 continue
             batch.append(item)
-        while len(batch) < self.max_batch:
-            try:
-                item = self._q.get_nowait()
-            except queue.Empty:
-                break
-            if item is _SHUTDOWN:
-                self._draining = True
-                break
-            batch.append(item)
+        with span("serve.admit"):
+            while len(batch) < self.max_batch:
+                try:
+                    item = self._q.get_nowait()
+                except queue.Empty:
+                    break
+                if item is _SHUTDOWN:
+                    self._draining = True
+                    break
+                batch.append(item)
         return batch
 
     def _serve_loop(self) -> None:
@@ -404,13 +408,23 @@ class WorkerServer:
             self._listener.close()
 
     def _serve(self, batch: list[_Request]) -> None:
-        stacked = {k: np.stack([r.payload[k] for r in batch])
-                   for k in batch[0].payload}
+        waited = time.monotonic() - min(r.arrived for r in batch)
+        with span("serve.batch", n=len(batch),
+                  req_ids=" ".join(str(r.req_id) for r in batch),
+                  wait_us=round(1e6 * waited)):
+            self._serve_batch(batch)
+
+    def _serve_batch(self, batch: list[_Request]) -> None:
+        with span("serve.stack"):
+            stacked = {k: np.stack([r.payload[k] for r in batch])
+                       for k in batch[0].payload}
         try:
-            out = self.serve_batch_fn(stacked)
+            with span("serve.device"):
+                out = self.serve_batch_fn(stacked)
             if isinstance(out, jax.Array):
                 self.devices |= out.devices()
-            out = np.asarray(out)
+            with span("serve.fetch"):
+                out = np.asarray(out)
         except Exception as e:  # repro: allow(broad-except) -- serve_batch_fn is arbitrary user code; answer MSG_ERR rather than hang the clients
             msg = f"{type(e).__name__}: {e}".encode()[:2000]
             for r in batch:
@@ -418,11 +432,12 @@ class WorkerServer:
                     _send_frame(r.conn, MSG_ERR,
                                 struct.pack("!I", r.req_id) + msg, r.lock)
             return
-        for i, r in enumerate(batch):
-            body = struct.pack("!IH", r.req_id, len(batch)) \
-                + pack_payload({"action": out[i]})
-            with contextlib.suppress(OSError):
-                _send_frame(r.conn, MSG_RESP, body, r.lock)
+        with span("serve.send"):
+            for i, r in enumerate(batch):
+                body = struct.pack("!IH", r.req_id, len(batch)) \
+                    + pack_payload({"action": out[i]})
+                with contextlib.suppress(OSError):
+                    _send_frame(r.conn, MSG_RESP, body, r.lock)
         self.n_served += len(batch)
         self.batch_sizes.append(len(batch))
 
@@ -660,13 +675,15 @@ class FleetClient:
             if s is None:
                 break
             req_id = next(self._ids)
-            try:
-                p = self.conns[s].request_async(req_id, body)
-            except ConnectionError as e:
-                last_err, tried = e, tried | {s}
-                continue
-            self.stats["per_server"][s] += 1
-            if not p.event.wait(timeout):
+            with span("fleet.request", req_id=req_id, client=client):
+                try:
+                    p = self.conns[s].request_async(req_id, body)
+                except ConnectionError as e:
+                    last_err, tried = e, tried | {s}
+                    continue
+                self.stats["per_server"][s] += 1
+                answered = p.event.wait(timeout)
+            if not answered:
                 self.conns[s].forget(req_id)
                 self.stats["timeouts"] += 1
                 last_err = FleetTimeout(
