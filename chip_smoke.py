@@ -89,10 +89,10 @@ def assert_close(got, want, what: str, **tol) -> None:
     np.testing.assert_allclose(got, want, err_msg=what, **tol)
 
 
-def standard_config(backend: str, **overrides):
+def standard_config(backend: str, max_batch: int = 8, **overrides):
     from repro.deploy import DeploymentConfig
     return DeploymentConfig.standard(k=4, c_in=12, h=84, head_dim=512,
-                                     max_batch=8, backend=backend,
+                                     max_batch=max_batch, backend=backend,
                                      **overrides)
 
 
@@ -139,7 +139,10 @@ def phase_head_and_stream() -> None:
     log("phase fused+head / fused+stream, vs xla")
     ref = Deployment.build(standard_config("xla"))
     head = Deployment.build(standard_config("fused+head"))
-    stream = Deployment.build(standard_config("fused+stream"))
+    # a stream chunk, and 4 of them past what one launch holds, need a
+    # max_batch above max_safe_batch
+    stream = Deployment.build(standard_config("fused+stream",
+                                              max_batch=128))
     params = head.init(jax.random.PRNGKey(0))
     n = 4 * stream.stream_chunk
     log(f"  max_safe_batch fused+head={head.max_safe_batch}, "

@@ -35,9 +35,10 @@ the ``benchmarks/roofline_table --miniconv`` table all re-derive from here.
 The Pallas execution paths consume the plan directly:
 ``repro.kernels.miniconv_pass.miniconv_encoder`` executes the whole plan as
 ONE fused kernel (layers chained through VMEM-resident intermediates,
-``TILE_H`` output rows per grid step), while the legacy per-pass kernel
-executes one ``pallas_call`` per :class:`ShaderPass` and serves as the
-reference oracle.
+``TILE_H`` output rows per grid step, a stride-s first layer folded
+space-to-depth: ``PassPlan.fold``, ``PassPlan.fused_layers``), while the
+legacy per-pass kernel executes one ``pallas_call`` per
+:class:`ShaderPass` and serves as the reference oracle.
 """
 from __future__ import annotations
 
@@ -284,13 +285,45 @@ class PassPlan:
     def max_pass_samples(self) -> int:
         return max(p.samples for p in self.passes)
 
+    # ---- the fused kernel's executed geometry ------------------------------
+    @property
+    def fold(self) -> int:
+        """Space-to-depth factor of layer 0 in the fused kernel: its stride
+        ``s`` when ``s > 1``, else 1 (layer 0 runs as planned)."""
+        return self.layers[0].stride
+
+    @property
+    def fused_layers(self) -> tuple[LayerPlan, ...]:
+        """The layers as the fused kernel executes them.
+
+        With ``fold`` s > 1, layer 0 runs space-to-depth: each s x s block
+        of its SAME-padded input (zero-padded up to a multiple of s) is one
+        pixel of s*s*C_in channels, and its k x k stride-s conv is a
+        ceil(k/s) x ceil(k/s) stride-1 conv over that input, with zero
+        weights where k does not divide by s.  Same arithmetic, 1/s^2 of
+        the taps, each contracting s^2 times the channels.  The other
+        layers run as planned; ``layers`` stays the logical schedule that
+        the passes, the wire and the counts read.
+        """
+        s = self.fold
+        if s == 1:
+            return self.layers
+        first = self.layers[0]
+        folded = dataclasses.replace(
+            first, kernel=-(-first.kernel // s), stride=1,
+            c_in=s * s * first.c_in, in_h=-(-first.padded_in_h // s),
+            in_w=-(-first.padded_in_w // s), pad_top=0, pad_bottom=0,
+            pad_left=0, pad_right=0)
+        return (folded, *self.layers[1:])
+
     # ---- VMEM residency of the fused kernel --------------------------------
     def fused_tiling(self, tile_h: int = 8) -> tuple[int, int, int]:
         """(tile_h, n_tiles, scratch_rows) of the fused kernel: ``tile_h``
         clamped to the feature height, the number of output-row tiles,
-        and the rows of the final layer's padded input, over-allocated so
-        the last tile's reads stay in bounds."""
-        last = self.layers[-1]
+        and the rows of the final layer's padded input as the kernel
+        executes it (:attr:`fused_layers`), over-allocated so the last
+        tile's reads stay in bounds."""
+        last = self.fused_layers[-1]
         tile_h = max(1, min(tile_h, self.out_h))
         n_tiles = -(-self.out_h // tile_h)
         rows_need_max = (n_tiles * tile_h - 1) * last.stride + last.kernel
@@ -309,17 +342,19 @@ class PassPlan:
         the padded-input scratch of layers 1..L-1, per-layer weights and
         biases, the output tile and — with a fused head — the laid-out
         head weight, its bias, the projection block and its accumulator.
-        Affine in batch, which is what the deployability check needs.
+        Layer 0's input block and weights are counted in the shape the
+        kernel holds them (:attr:`fused_layers`: folded when ``fold`` >
+        1).  Affine in batch, which is what the deployability check needs.
         """
-        first, last = self.layers[0], self.layers[-1]
+        layers = self.fused_layers
+        first, last = layers[0], layers[-1]
         tile_h, n_tiles, scratch_rows = self.fused_tiling(tile_h)
-        x0_rows = scratch_rows if len(self.layers) == 1 \
-            else first.padded_in_h
+        x0_rows = scratch_rows if len(layers) == 1 else first.padded_in_h
         per_frame = (2 if streamed else 1) * tiled_bytes(
             (x0_rows, first.padded_in_w, first.c_in_pad), itemsize)
         fixed = 2 * tiled_bytes((tile_h, last.out_w, last.c_out_pad),
                                 itemsize)                        # out tile
-        for i, l in enumerate(self.layers):
+        for i, l in enumerate(layers):
             fixed += (tiled_bytes((l.kernel, l.kernel, l.c_in_pad,
                                    l.c_out_pad), itemsize)
                       + tiled_bytes((1, l.c_out_pad), itemsize))

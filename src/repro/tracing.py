@@ -25,6 +25,9 @@ SCOPES = {
                       "SAME border of the fused kernels' input",
     "miniconv.weights": "per-layer weight and bias padding to RGBA "
                         "multiples",
+    "miniconv.s2d": "the space-to-depth fold of a stride-s first layer's "
+                    "padded input and weights (inside miniconv.input and "
+                    "miniconv.weights); absent where the plan does not fold",
     "miniconv.head_tile": "the projection weight tiled for the kernel's "
                           "epilogue, and its lane padding",
     "miniconv.kernel": "the fused encoder pallas_call",

@@ -424,11 +424,10 @@ def test_kernel_interpret_negative():
 
 
 def test_vmem_audit_default_budget():
-    # under the real budget only the known c_in=4 400x400 limitation of
-    # today's channels-on-lanes layout fires (carried in the committed
-    # baseline, not fixed)
-    findings = audit_vmem_budgets()
-    assert all("400x400" in f.message for f in findings)
+    # under the real budget every audited config launches: c_in=4 at
+    # 400x400, which did not before layer 0 was folded space-to-depth,
+    # takes 2 frames with the head
+    assert audit_vmem_budgets() == []
 
 
 def test_vmem_audit_tiny_budget_fires():

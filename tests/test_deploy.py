@@ -237,14 +237,16 @@ def test_vmem_bytes_affine_in_batch():
 
 
 def test_vmem_input_block_counts_compiler_tiles():
-    """The (B, 86, 86, 12) padded input block of the standard 84x84 plan
-    is laid out in (8, 128) tiles: 86 x 88 x 128 x 4 bytes per frame, not
-    the 86 x 86 x 12 x 4 it holds."""
+    """A (B, 86, 86, 12) block is laid out in (8, 128) tiles: 86 x 88 x
+    128 x 4 bytes per frame, not the 86 x 86 x 12 x 4 it holds.  The
+    standard 84x84 plan's input block is that padded input folded
+    space-to-depth, (B, 43, 43, 48): 43 x 48 x 128 x 4 bytes per frame."""
     per_frame = 86 * 88 * 128 * 4
     assert tiled_bytes((86, 86, 12)) == per_frame
     assert tiled_bytes((48, 86, 86, 12)) == 48 * per_frame
     plan = standard_spec(c_in=12, k=4).plan(84)
-    assert plan.vmem_bytes(2) - plan.vmem_bytes(1) == per_frame
+    assert plan.vmem_bytes(2) - plan.vmem_bytes(1) == tiled_bytes(
+        (43, 43, 48)) == 43 * 48 * 128 * 4
     # sub-32-bit types pack more rows per sublane tile
     assert tiled_bytes((86, 86, 12), itemsize=2) == 86 * 96 * 128 * 2
     assert tiled_bytes((5,)) == 8 * 128 * 4
@@ -256,22 +258,24 @@ def test_standard_deployment_max_safe_batch():
     launch at exactly these sizes)."""
     plan = standard_spec(c_in=12, k=4).plan(84)
     head = plan.head(512)
-    assert plan.max_safe_batch() == 25
-    assert plan.max_safe_batch(head=head) == 24
-    assert plan.max_safe_batch(head=head, streamed=True) == 12
+    assert plan.max_safe_batch() == 93
+    assert plan.max_safe_batch(head=head) == 91
+    assert plan.max_safe_batch(head=head, streamed=True) == 45
     dep = Deployment.build(DeploymentConfig.standard(
         k=4, c_in=12, h=84, backend="fused+stream", interpret=False,
         max_batch=64))
-    assert dep.max_safe_batch == 24 and dep.stream_chunk == 12
+    assert dep.max_safe_batch == 91 and dep.stream_chunk == 45
 
 
 def test_unlaunchable_when_one_frame_exceeds_vmem():
-    """c_in=4 at 400x400: one tile-padded frame plus the intermediates
-    exceeds the budget, so a compiled fused build has no launchable batch."""
-    plan = standard_spec(c_in=4, k=4).plan(400)
+    """c_in=4 at 640x640: one tile-padded frame plus the intermediates
+    exceeds the budget, so a compiled fused build has no launchable batch
+    (400x400, unlaunchable before layer 0 was folded, now takes 3)."""
+    assert standard_spec(c_in=4, k=4).plan(400).max_safe_batch() == 3
+    plan = standard_spec(c_in=4, k=4).plan(640)
     assert plan.max_safe_batch() == 0
     assert plan.vmem_bytes(1) > DEFAULT_VMEM_LIMIT
-    cfg = DeploymentConfig.standard(k=4, c_in=4, h=400, backend="fused",
+    cfg = DeploymentConfig.standard(k=4, c_in=4, h=640, backend="fused",
                                     interpret=False)
     with pytest.raises(ValueError, match="max_safe_batch=0"):
         Deployment.build(cfg)

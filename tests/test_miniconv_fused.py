@@ -8,6 +8,7 @@ import pytest
 
 from repro.core.miniconv import (LayerSpec, MiniConvSpec, miniconv_apply,
                                  miniconv_init, standard_spec)
+from repro.kernels.miniconv_pass import miniconv_encoder_stream
 from repro.kernels.ops import miniconv_layer
 
 MODES = ("per_pass", "grouped", "fused")
@@ -118,3 +119,63 @@ def test_bad_mode_raises():
     x = jnp.zeros((1, 16, 16, 4))
     with pytest.raises(ValueError):
         miniconv_apply(params, spec, x, use_kernel="warp")
+
+
+FOLD_CASES = {
+    # name: (spec, (h, w), batch, head_dim, stream chunk)
+    "84-c9-b8-head": (standard_spec(c_in=9, k=4), (84, 84), 8, 64, None),
+    "84-c12-b1": (standard_spec(c_in=12, k=4), (84, 84), 1, None, None),
+    "256-c4-streamed": (standard_spec(c_in=4, k=4), (256, 256), 3, 32, 2),
+    "k3s2-first": (MiniConvSpec((LayerSpec(3, 2, 5, 8),
+                                 LayerSpec(3, 2, 8, 4))), (23, 17), 2, None,
+                   None),
+    "single-layer": (MiniConvSpec((LayerSpec(4, 2, 6, 6),)), (19, 22), 2,
+                     24, None),
+    "stride1-first": (MiniConvSpec((LayerSpec(3, 1, 8, 8),
+                                    LayerSpec(3, 2, 8, 4))), (16, 16), 2,
+                      None, None),
+}
+
+
+@pytest.mark.parametrize("case", list(FOLD_CASES))
+def test_fused_fold_parity(case):
+    """The fused kernel with layer 0 folded space-to-depth (plan.fold > 1)
+    matches the plain XLA reference: features, and the fused projection
+    where the case has a head; the streamed case runs one pipelined
+    launch over whole and ragged chunks.  A stride-1 first layer is not
+    folded: the plan says so and the compiled step holds no op under
+    ``miniconv.s2d``."""
+    spec, (h, w), batch, head_dim, chunk = FOLD_CASES[case]
+    plan = spec.plan(h, w)
+    assert plan.fold == spec.layers[0].stride
+    assert (plan.fused_layers == plan.layers) == (plan.fold == 1)
+    params = miniconv_init(jax.random.PRNGKey(0), spec)
+    x = jax.random.uniform(jax.random.PRNGKey(1),
+                           (batch, h, w, spec.layers[0].c_in))
+    head = None
+    if head_dim:
+        kw, kb = jax.random.split(jax.random.PRNGKey(2))
+        head = {"kernel": jax.random.normal(
+                    kw, (plan.flat_features, head_dim)) / plan.flat_features,
+                "bias": 0.1 * jax.random.normal(kb, (head_dim,))}
+
+    def fused(p, x):
+        if chunk is None:
+            return miniconv_apply(p, spec, x, use_kernel="fused", plan=plan,
+                                  head=head)
+        n = len(spec.layers)
+        return miniconv_encoder_stream(
+            x, [p[f"layer{i}"]["kernel"] for i in range(n)],
+            [p[f"layer{i}"]["bias"] for i in range(n)], plan, chunk_b=chunk,
+            head_w=head["kernel"] if head else None,
+            head_b=head["bias"] if head else None, pipelined=True)
+
+    compiled = jax.jit(fused).lower(params, x).compile()
+    assert ("miniconv.s2d" in compiled.as_text()) == (plan.fold > 1)
+    out = compiled(params, x)
+    ref = miniconv_apply(params, spec, x, head=head)
+    if head is None:
+        out, ref = (out,), (ref,)
+    for got, want in zip(out, ref):
+        assert got.shape == want.shape
+        np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)

@@ -9,8 +9,9 @@ from repro.core.latency import SplitConfig
 from repro.core.miniconv import (LayerSpec, MiniConvSpec, ShaderBudget,
                                  miniconv_feature_shape, standard_spec)
 from repro.core.passplan import (build_pass_plan, count_passes, out_size,
-                                 out_spatial_chain, same_pads)
+                                 out_spatial_chain, same_pads, tiled_bytes)
 from repro.core.wire import feature_bytes
+from repro.kernels.miniconv_pass import miniconv_encoder_stream
 
 SPECS = {
     "k4": standard_spec(12, 4),
@@ -108,3 +109,91 @@ def test_texture_bindings_pack_rgba():
     p0 = plan.passes[0]
     assert p0.texture_bindings == ((0, 4), (4, 8), (8, 12))
     assert p0.in_textures == 3 and p0.samples == 4 * 4 * 3
+
+
+# ---------------------------------------------------------------------------
+# space-to-depth fold of a stride-s first layer (the fused kernel's geometry)
+# ---------------------------------------------------------------------------
+
+def test_fold_keeps_the_logical_plan():
+    """``fold`` is layer 0's stride; ``fused_layers`` folds layer 0 alone
+    and leaves the logical layers (passes, wire, counts) as they were."""
+    plan = build_pass_plan(standard_spec(12, 4), 84)
+    assert plan.fold == 2
+    f0, l0 = plan.fused_layers[0], plan.layers[0]
+    assert (f0.kernel, f0.stride, f0.c_in, f0.c_in_pad) == (2, 1, 48, 48)
+    assert (f0.padded_in_h, f0.padded_in_w) == (43, 43)
+    assert (f0.out_h, f0.out_w, f0.c_out) == (l0.out_h, l0.out_w, l0.c_out)
+    assert plan.fused_layers[1:] == plan.layers[1:]
+    assert (l0.kernel, l0.stride, l0.c_in) == (4, 2, 12)
+    assert plan.flops_per_frame == standard_spec(12, 4).flops_per_frame(84)
+    # a k=3 first layer folds to 2x2 taps over the zero-padded kernel
+    k3 = build_pass_plan(MiniConvSpec((LayerSpec(3, 2, 5, 8),)), 23, 17)
+    assert (k3.fused_layers[0].kernel, k3.fused_layers[0].c_in_pad) == (2, 20)
+    # a stride-1 first layer runs as planned
+    s1 = build_pass_plan(SPECS["single"], 64)
+    assert s1.fold == 1 and s1.fused_layers is s1.layers
+
+
+def _input_block(plan, batch, chunk=None):
+    """The block shape of the fused launch's input BlockSpec, read from
+    the ``pallas_call`` in the launch's jaxpr."""
+    S = lambda *shape: jax.ShapeDtypeStruct(shape, jax.numpy.float32)
+    ws = [S(l.kernel, l.kernel, l.c_in, l.c_out) for l in plan.layers]
+    bs = [S(l.c_out) for l in plan.layers]
+    x = S(batch, plan.in_h, plan.in_w, plan.layers[0].c_in)
+    jaxpr = jax.make_jaxpr(lambda x, ws, bs: miniconv_encoder_stream(
+        x, ws, bs, plan, chunk_b=chunk or batch, interpret=False,
+        pipelined=True))(x, ws, bs)
+
+    def find(j):
+        for eqn in j.eqns:
+            if eqn.primitive.name == "pallas_call":
+                return eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                found = find(sub)
+                if found is not None:
+                    return found
+
+    block = find(jaxpr.jaxpr).params["grid_mapping"].block_mappings[0]
+    return tuple(getattr(b, "block_size", b) for b in block.block_shape)
+
+
+@pytest.mark.parametrize("spec,size,streamed", [
+    (standard_spec(12, 4), (84, 84), False),
+    (standard_spec(9, 4), (84, 84), False),
+    (standard_spec(4, 4), (256, 256), True),
+    (MiniConvSpec((LayerSpec(4, 2, 6, 6),)), (19, 22), False),
+    (SPECS["c6"], (33, 19), True),
+], ids=["84-c12", "84-c9", "256-c4-streamed", "single-layer", "c6"])
+def test_fold_input_block_bytes_match_the_kernel_blockspec(spec, size,
+                                                           streamed):
+    """The VMEM model's per-frame input bytes are the tiled bytes of the
+    block the kernel really takes: layer 0's input folded space-to-depth,
+    counted twice where a streamed chunk is double-buffered."""
+    plan = build_pass_plan(spec, *size)
+    block = _input_block(plan, 3, chunk=1 if streamed else None)
+    assert block[0] == (1 if streamed else 3)
+    f0 = plan.fused_layers[0]
+    assert block[2:] == (f0.padded_in_w, f0.c_in_pad)
+    per_frame = (plan.vmem_bytes(2, streamed=streamed)
+                 - plan.vmem_bytes(1, streamed=streamed))
+    assert per_frame == (2 if streamed else 1) * tiled_bytes(block[1:])
+    if size == (84, 84) and spec.layers[0].c_in == 12:
+        assert block[1:] == (43, 43, 48)
+
+
+def test_fold_max_safe_batch():
+    """Launchable micro-batches of the folded kernel: the 84x84 c_in=12
+    deployment (25 / 24 / 12 before the fold) and 256x256 c_in=4 (2 / 2 /
+    1), which now takes B=8 in one whole-batch launch with the head."""
+    plan = build_pass_plan(standard_spec(12, 4), 84)
+    head = plan.head(512)
+    assert plan.max_safe_batch() == 93
+    assert plan.max_safe_batch(head=head) == 91
+    assert plan.max_safe_batch(head=head, streamed=True) == 45
+    plan = build_pass_plan(standard_spec(4, 4), 256)
+    head = plan.head(512)
+    assert plan.max_safe_batch() == 9
+    assert plan.max_safe_batch(head=head) == 8
+    assert plan.max_safe_batch(head=head, streamed=True) == 4

@@ -6,7 +6,8 @@ and the chip refuses: unsupported primitives, misaligned slices and
 blocks, and more VMEM than the launch may use.  They compile the main
 path — ``Deployment.build`` -> the fused edge half / encoder — at the
 paper's standard deployment (k=4, c_in=12, 84x84, head_dim=512) at B=1,
-at B=8 and streamed, and a fused launch at exactly ``max_safe_batch``.
+at B=8 and streamed, a fused launch at exactly ``max_safe_batch``, and
+whole-batch launches of RGBA frames at 256x256 and 400x400.
 Nothing runs: a compile that passes is not a chip run.
 
 The topology is described inside a fixture (never at import), so every
@@ -20,6 +21,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.miniconv import standard_spec
 from repro.deploy import Deployment, DeploymentConfig
 from repro.kernels.miniconv_pass import miniconv_encoder
 
@@ -91,7 +93,7 @@ def test_fused_backends_compile(one_chip, backend, batch):
 def test_fused_stream_compiles_streamed(one_chip):
     """fused+stream at 4x its chunk: one pipelined launch whose grid walks
     the chunks, past what a whole-batch launch could hold."""
-    dep = _deployment("fused+stream")
+    dep = _deployment("fused+stream", max_batch=128)
     batch = 4 * dep.stream_chunk
     assert batch > dep.max_safe_batch
     _compile_main_path(dep, batch, one_chip)
@@ -100,10 +102,10 @@ def test_fused_stream_compiles_streamed(one_chip):
 def test_fused_over_budget_batch_compiles_streamed(one_chip):
     """A plain fused deployment whose max_batch exceeds max_safe_batch is
     pipelined by Deployment.build, and that launch compiles."""
-    dep = _deployment("fused", max_batch=64)
+    dep = _deployment("fused", max_batch=128)
     assert dep.stream_chunk is not None
-    assert dep.stream_chunk <= dep.max_safe_batch < 64
-    _compile_main_path(dep, 64, one_chip)
+    assert dep.stream_chunk <= dep.max_safe_batch < 128
+    _compile_main_path(dep, 128, one_chip)
 
 
 @pytest.mark.parametrize("with_head", [False, True])
@@ -112,19 +114,36 @@ def test_fused_launch_compiles_at_max_safe_batch(one_chip, with_head):
     fused launch of exactly max_safe_batch frames fits the limit the
     kernel hands the compiler."""
     dep = _deployment("fused+head" if with_head else "fused")
-    plan = dep.plan
     head = dep.head_plan if with_head else None
-    batch = plan.max_safe_batch(head=head)
+    batch = dep.plan.max_safe_batch(head=head)
     assert batch == dep.max_safe_batch >= 8
+    _compile_whole_batch(dep.plan, head, batch, one_chip)
+
+
+@pytest.mark.parametrize("size,c_in,batch", [(256, 4, 8), (400, 4, 2)])
+def test_folded_launch_compiles_at_larger_frames(one_chip, size, c_in,
+                                                 batch):
+    """Folding layer 0 space-to-depth shrinks the input block about 3x:
+    256x256 RGBA takes B=8 with the head in one whole-batch launch, and
+    400x400 RGBA, which no launch could hold before, takes 2."""
+    plan = standard_spec(c_in=c_in, k=4).plan(size)
+    head = plan.head(HEAD_DIM)
+    assert plan.fold == 2 and plan.max_safe_batch(head=head) == batch
+    _compile_whole_batch(plan, head, batch, one_chip)
+
+
+def _compile_whole_batch(plan, head, batch, one_chip):
+    """Compile one whole-batch fused launch of ``batch`` frames."""
     S = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32,
                                            sharding=one_chip)
     ws = [S((l.kernel, l.kernel, l.c_in, l.c_out)) for l in plan.layers]
     bs = [S((l.c_out,)) for l in plan.layers]
-    hw = S((plan.flat_features, HEAD_DIM)) if with_head else None
-    hb = S((HEAD_DIM,)) if with_head else None
+    hw = S((plan.flat_features, head.out_dim)) if head else None
+    hb = S((head.out_dim,)) if head else None
 
     def launch(x, ws, bs, hw, hb):
         return miniconv_encoder(x, ws, bs, plan, head_w=hw, head_b=hb,
                                 interpret=False)
 
-    _compile(launch, S((batch, X, X, C_IN)), ws, bs, hw, hb)
+    _compile(launch, S((batch, plan.in_h, plan.in_w, plan.layers[0].c_in)),
+             ws, bs, hw, hb)

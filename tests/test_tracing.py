@@ -24,8 +24,8 @@ LAYERS = [
     {"kernel": 3, "stride": 2, "c_in": 16, "c_out": 16, "activation": "relu"},
     {"kernel": 3, "stride": 2, "c_in": 16, "c_out": 4,
      "activation": "sigmoid"}]
-ENCODE_SCOPES = ("miniconv.input", "miniconv.weights", "miniconv.head_tile",
-                 "miniconv.kernel", "miniconv.out")
+ENCODE_SCOPES = ("miniconv.input", "miniconv.weights", "miniconv.s2d",
+                 "miniconv.head_tile", "miniconv.kernel", "miniconv.out")
 
 
 def _deployment(backend="fused+head", **kw):
@@ -65,6 +65,21 @@ def test_encode_step_carries_its_scopes(dep, params):
         assert _under(names, "miniconv.encode", name), name
 
 
+def test_folded_step_carries_the_s2d_scope():
+    """At 84x84 the stride-2 first layer is folded space-to-depth: its
+    input's fold sits under miniconv.input and its weights' under
+    miniconv.weights, both as miniconv.s2d."""
+    dep = _deployment(in_h=84, in_w=84)
+    assert dep.plan.fold == 2
+    params = jax.eval_shape(dep.init, jax.random.PRNGKey(0))
+    names = _op_names(dep.encoder.apply, params,
+                      jax.ShapeDtypeStruct((8, 84, 84, 9), jnp.float32))
+    assert _under(names, "miniconv.encode", "miniconv.input",
+                  "miniconv.s2d")
+    assert _under(names, "miniconv.encode", "miniconv.weights",
+                  "miniconv.s2d")
+
+
 def test_split_encode_step_carries_its_scopes():
     dep = _deployment(backend="fused", head_placement="server")
     params = dep.init(jax.random.PRNGKey(1))
@@ -98,6 +113,9 @@ def test_server_half_carries_its_scopes(dep, params):
 
 
 def test_unregistered_names_are_refused():
+    assert "miniconv.s2d" in tracing.SCOPES
+    with tracing.scope("miniconv.s2d"):
+        pass
     with pytest.raises(ValueError, match="unregistered scope"):
         tracing.scope("miniconv.layer0")
     with pytest.raises(ValueError, match="unregistered span"):

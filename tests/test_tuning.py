@@ -324,13 +324,13 @@ def test_miniconv_apply_stream_chunk_param():
 
 def test_build_pipelines_over_budget_compiled_batch():
     """The paper-scale serving config that USED to be rejected (X=84
-    fused+head, max_batch=64 > max_safe_batch) now builds, streaming the
+    fused+head, max_batch=128 > max_safe_batch) now builds, streaming the
     launch in VMEM-safe chunks, and logs the decision with the computed
     max_safe_batch and the tuner's suggestion."""
     cfg = DeploymentConfig.standard(k=4, c_in=12, h=84, backend="fused+head",
-                                    interpret=False, max_batch=64)
+                                    interpret=False, max_batch=128)
     dep = Deployment.build(cfg)
-    assert 1 <= dep.stream_chunk <= dep.max_safe_batch < 64
+    assert 1 <= dep.stream_chunk <= dep.max_safe_batch < 128
     note = " ".join(dep.build_log)
     assert "pipelining" in note and "max_safe_batch" in note
     assert "tile_h" in note and "micro_batch" in note   # tuner suggestion
