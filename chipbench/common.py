@@ -2,12 +2,17 @@
 from __future__ import annotations
 
 import contextlib
+import sys
 
 import numpy as np
 
 # The harness's own host spans; the trace reduction names idle gaps by
 # them.
-SPAN_NAMES = ("window", "dispatch", "host_sync", "generate", "serve_wait")
+SPAN_NAMES = ("window", "dispatch", "host_sync")
+
+
+def log(msg: str) -> None:
+    print(f"[chipbench] {msg}", file=sys.stderr, flush=True)
 
 
 class Spans:

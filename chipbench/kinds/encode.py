@@ -36,6 +36,11 @@ class Encode:
         k_params, k_frames = common.jax_keys(self.rng, 2)
         self.dep = Deployment.build(DeploymentConfig.from_dict(
             common.manifest(cfg, backend=tr["backend"])))
+        for line in self.dep.build_log:
+            common.log(f"build: {line}")
+        common.log(f"launch of {self.batch}: max_safe_batch "
+                   f"{self.dep.max_safe_batch}, stream_chunk "
+                   f"{self.dep.stream_chunk}")
         shape = (self.pool, self.batch, cfg["in_h"], cfg["in_w"],
                  cfg["layers"][0]["c_in"])
         self.params, frames = jax.jit(

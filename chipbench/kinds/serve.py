@@ -2,35 +2,47 @@
 deployment's server fleet (``Deployment.fleet``), over its localhost
 sockets.
 
-Each client sends one decision every ``1 / rate_hz`` seconds and waits
-for its action before the next; a decision is due at its slot of the
-grid whatever happened before, and its latency runs from that slot to
-the action's arrival, so a backlog counts.  The clients' phases are the
-uniform stagger of ``clients`` slots over one period, dealt to the
-clients in an order drawn from the seed: every seed offers the same
-arrivals.  Each client cycles ``payloads_per_client`` uint8 payloads
-drawn from a pool of ``payload_pool``, made in set-up by the reference
-encoder from seeded frames (the edge devices' work, not timed).
+The clients run in a process of their own (``chipbench/loadgen.py``,
+started in set-up): one thread over one socket to the one replica, so
+that they share neither the interpreter lock nor the CPU time of the
+process that serves.  Each client sends one decision every
+``1 / rate_hz`` seconds and waits for its action before the next; a
+decision is due at its slot of the grid whatever happened before, and
+its latency runs from that slot to the action's arrival, so a backlog
+counts.  The clients' phases are the uniform stagger of ``clients``
+slots over one period, dealt to the clients in an order drawn from the
+seed: every seed offers the same arrivals.  Each client cycles
+``payloads_per_client`` uint8 payloads drawn from a pool of
+``payload_pool``, made in set-up by the reference encoder from seeded
+frames (the edge devices' work, not timed).
 
 Traffic parameters (``traffic/<name>.json``): ``backend``,
-``n_servers``, ``clients``, ``rate_hz``, ``payload_pool``,
-``payloads_per_client``, ``warm_requests``.
+``n_servers`` (1), ``clients``, ``rate_hz``, ``payload_pool``,
+``payloads_per_client``, ``warm_requests`` (sent one at a time over the
+clients' socket in set-up).
 
-Every action the window produced is checked once it has closed, against
-the reference's decode and projection of its payload.  A request that
-fails counts as missing for the latency and makes the run incorrect.
+The end-to-end metric is the median latency of every decision due in
+the window; the p95, its two halves and the largest are logged.  Every
+action the window produced is checked once it has closed, against the
+reference's decode and projection of its payload.  A request that fails,
+or gets no answer, makes the run incorrect.
 """
 from __future__ import annotations
 
-import threading
+import pickle
+import subprocess
+import sys
 import time
+from pathlib import Path
 
 import numpy as np
 
 from chipbench import common
 from chipbench.reference import miniconv as ref
 
-END_TO_END = "decision_p95_ms"
+LOADGEN = Path(__file__).resolve().parents[1] / "loadgen.py"
+
+END_TO_END = "decision_p50_ms"
 
 
 def _stagger(clients: int, period: float, rng) -> np.ndarray:
@@ -42,10 +54,10 @@ class Serve:
     def __init__(self, cell, seed: int, spans: common.Spans):
         import jax
         from repro.deploy import Deployment, DeploymentConfig
-        from repro.serving.realfleet import pack_payload
+        from repro.serving import realfleet
 
         cfg, tr = cell.config, cell.traffic
-        self.cfg, self.spans = cfg, spans
+        self.cfg = cfg
         self.rate = float(tr["rate_hz"])
         self.rng = np.random.default_rng(seed)
         k_params, k_frames = common.jax_keys(self.rng, 2)
@@ -63,16 +75,39 @@ class Serve:
         self.params, wire = make(k_params, k_frames)
         wire = jax.tree.map(np.asarray, wire)
         self.wire = wire
-        self.bodies = [pack_payload({k: v[i] for k, v in wire.items()})
+        self.bodies = [realfleet.pack_payload({k: v[i]
+                                                for k, v in wire.items()})
                        for i in range(pool)]
         self.pool, self.per = pool, int(tr["payloads_per_client"])
         self.deal(int(tr["clients"]))
+        if int(tr["n_servers"]) != 1:
+            raise ValueError("serve traffic drives one replica; "
+                             f"n_servers is {tr['n_servers']}")
         dep = Deployment.build(DeploymentConfig.from_dict(
             common.manifest(cfg, backend=tr["backend"])))
-        self.fleet = dep.fleet(self.params, n_servers=int(tr["n_servers"]))
+        self.fleet = dep.fleet(self.params, n_servers=1)
         self.records: list = []
-        for i in range(int(tr["warm_requests"])):
-            self.fleet.request(self.bodies[i % pool], client=i)
+        self.clients_proc = subprocess.Popen(
+            [sys.executable, str(LOADGEN)], stdin=subprocess.PIPE,
+            stdout=subprocess.PIPE)
+        self._tell({"addr": self.fleet.workers[0].addr,
+                    "bodies": self.bodies, "warm": int(tr["warm_requests"]),
+                    "msg": {"req": realfleet.MSG_REQ,
+                            "resp": realfleet.MSG_RESP,
+                            "err": realfleet.MSG_ERR}})
+        if self._hear() != "ready":
+            raise RuntimeError("the load generator did not start")
+
+    def _tell(self, message) -> None:
+        pickle.dump(message, self.clients_proc.stdin)
+        self.clients_proc.stdin.flush()
+
+    def _hear(self):
+        try:
+            return pickle.load(self.clients_proc.stdout)
+        except EOFError as e:
+            raise RuntimeError(f"the load generator exited with "
+                               f"{self.clients_proc.wait()}") from e
 
     def deal(self, clients: int) -> None:
         """Phases and payloads of ``clients`` clients, from the seed."""
@@ -82,62 +117,57 @@ class Serve:
             for _ in range(clients)]
         self.offsets = _stagger(clients, 1.0 / self.rate, self.rng)
 
-    def _client(self, c: int, t0: float, seconds: float, out: list) -> None:
-        span, request = self.spans.span, self.fleet.request
-        period = 1.0 / self.rate
-        payloads = self.client_payloads[c]
-        free = t0
-        k = 0
-        while self.offsets[c] + k * period < seconds:
-            with span("generate"):
-                due = t0 + self.offsets[c] + k * period
-                j = int(payloads[k % len(payloads)])
-                now = time.monotonic()
-                if now < due:
-                    time.sleep(due - now)
-            sent = time.monotonic()
-            try:
-                with span("serve_wait"):
-                    action = request(self.bodies[j], client=c)
-            except Exception as e:  # repro: allow(broad-except) -- any failure is a missing answer, counted and reported
-                action = e
-            done = time.monotonic()
-            out.append((c, j, due, sent - max(due, free), done - due, action))
-            free = done
-            k += 1
+    @staticmethod
+    def _action(answer):
+        """The action of an answer body (``!H`` batch size, packed
+        action), or the exception that stands for a failed request."""
+        from repro.serving.realfleet import unpack_payload
+        if isinstance(answer, bytes):
+            return unpack_payload(answer[2:])["action"]
+        if answer is None:
+            return TimeoutError("no answer")
+        return RuntimeError(f"the worker answered an error: {answer}")
 
     def window(self, seconds: float) -> dict:
         worker = self.fleet.workers[0]
         n0 = len(worker.batch_sizes)
-        t0 = time.monotonic() + 0.25
-        outs = [[] for _ in range(self.clients)]
-        threads = [threading.Thread(target=self._client,
-                                    args=(c, t0, seconds, outs[c]))
-                   for c in range(self.clients)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
+        self._tell({"seconds": seconds, "rate_hz": self.rate,
+                    "offsets": [float(o) for o in self.offsets],
+                    "payloads": [[int(j) for j in p]
+                                 for p in self.client_payloads]})
+        got = self._hear()
         t_end = time.monotonic()
         batches = list(worker.batch_sizes[n0:])
-        self.records = [r for o in outs for r in o]
+        t0 = got["t0"]
+        self.records = [(c, j, due, lag, latency, self._action(answer))
+                        for c, j, due, lag, latency, answer
+                        in got["records"]]
         lat = np.array([r[4] for r in self.records])
         lag = np.array([r[3] for r in self.records])
         failed = sum(isinstance(r[5], Exception) for r in self.records)
         half = t0 + seconds / 2
         early = [r[4] for r in self.records if r[2] < half]
         late = [r[4] for r in self.records if r[2] >= half]
-        return {"metrics": {END_TO_END: 1e3 * common.percentile(lat, 95)},
+        counters = {
+            "batches": batches, "requests": len(self.records),
+            "gen_lag_p95_ms": 1e3 * common.percentile(lag, 95),
+            "p95_ms": 1e3 * common.percentile(lat, 95),
+            "p95_first_half_ms": 1e3 * common.percentile(early, 95),
+            "p95_second_half_ms": 1e3 * common.percentile(late, 95),
+            "max_ms": 1e3 * float(lat.max()), "window_s": t_end - t0}
+        common.log("decision latency: " + ", ".join(
+            f"{k} {counters[k]:.4f}" for k in (
+                "p95_ms", "p95_first_half_ms", "p95_second_half_ms",
+                "max_ms", "gen_lag_p95_ms")))
+        return {"metrics": {END_TO_END: 1e3 * common.percentile(lat, 50)},
                 "attempted": len(self.records), "failed": failed,
-                "counters": {
-                    "batches": batches, "requests": len(self.records),
-                    "gen_lag_p95_ms": 1e3 * common.percentile(lag, 95),
-                    "p50_ms": 1e3 * common.percentile(lat, 50),
-                    "p95_first_half_ms": 1e3 * common.percentile(early, 95),
-                    "p95_second_half_ms": 1e3 * common.percentile(late, 95),
-                    "window_s": t_end - t0}}
+                "counters": counters}
 
     def release(self) -> None:
+        self._tell(None)
+        self.clients_proc.stdin.close()
+        self.clients_proc.wait(timeout=60)
+        self.clients_proc.stdout.close()
         leaked = self.fleet.close()
         self.fleet = None
         if leaked:

@@ -38,14 +38,11 @@ for _p in (str(CHECKOUT / "src"), str(CHECKOUT)):
         sys.path.insert(0, _p)
 
 from chipbench import common, manifest, trace  # noqa: E402
+from chipbench.common import log  # noqa: E402
 
 # events JAX records when it lowers or compiles a program
 COMPILE_EVENTS = ("/jax/core/compile/jaxpr_to_mlir_module_duration",
                   "/jax/core/compile/backend_compile_duration")
-
-
-def log(msg: str) -> None:
-    print(f"[chipbench] {msg}", file=sys.stderr, flush=True)
 
 
 def find_chips(n: int):
@@ -109,6 +106,19 @@ def peaks_for(kind: str) -> dict:
     return table[kind]
 
 
+def profile_options(traffic: dict):
+    """The profiler's options for a traced run: JAX's defaults, unless the
+    traffic file sets ``python_tracer_level`` (0 leaves Python function
+    calls out of the trace, whose cost on every call would change a
+    host-bound path's timing)."""
+    if "python_tracer_level" not in traffic:
+        return None
+    import jax
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = int(traffic["python_tracer_level"])
+    return options
+
+
 def parse(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -164,7 +174,9 @@ def _run(args, cell, devices, checkout: Path, require_chip: bool) -> dict:
     traced = bool(args.trace) and require_chip
     if traced:
         shutil.rmtree(trace_dir, ignore_errors=True)
-        jax.profiler.start_trace(str(trace_dir))
+        jax.profiler.start_trace(str(trace_dir),
+                                 profiler_options=profile_options(
+                                     cell.traffic))
     counter.armed = True
     try:
         with spans.span("window"):

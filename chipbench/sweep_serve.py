@@ -47,8 +47,8 @@ def main(argv=None) -> None:
             w = work.window(args.seconds)
             c = w["counters"]
             point = {"clients": n, "offered_per_s": n * work.rate,
-                     "p95_ms": w["metrics"]["decision_p95_ms"],
-                     "p50_ms": c["p50_ms"],
+                     "p95_ms": c["p95_ms"],
+                     "p50_ms": w["metrics"]["decision_p50_ms"],
                      "p95_first_half_ms": c["p95_first_half_ms"],
                      "p95_second_half_ms": c["p95_second_half_ms"],
                      "gen_lag_p95_ms": c["gen_lag_p95_ms"],

@@ -126,3 +126,17 @@ def test_benchmark_files_alone_give_no_result(tmp_path):
     proc = _command(tmp_path)
     assert proc.returncode != 0
     assert _no_result(proc.stdout)
+
+
+def test_python_tracer_off_only_where_the_traffic_says():
+    import jax
+    default = jax.profiler.ProfileOptions()
+    assert run.profile_options({"kind": "encode"}) is None
+    options = run.profile_options({"kind": "serve",
+                                   "python_tracer_level": 0})
+    assert options.python_tracer_level == 0 != default.python_tracer_level
+    assert options.host_tracer_level == default.host_tracer_level
+    for w in BENCH["workloads"]:
+        traffic = manifest.cell(w["name"]).traffic
+        want = 0 if traffic["kind"] == "serve" else None
+        assert traffic.get("python_tracer_level") == want, w["name"]

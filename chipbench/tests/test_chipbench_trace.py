@@ -35,6 +35,24 @@ def test_summary_by_hand():
     assert s.idle_gaps() == [["none", 3.0], ["host_sync", 2.0],
                              ["none", 1.0]]
     assert s.idle_gaps(least_s=1.5) == [["none", 3.0], ["host_sync", 2.0]]
+    assert s.program_spans == [] and s.program_spans_named("serve.batch") \
+        == []
+
+
+def test_program_spans_by_hand():
+    s = trace.Summary(
+        window=(1.0, 5.0), ops=[[("k", 1.0, 2.0)]], modules=[[]],
+        spans=[("window", 1.0, 5.0)],
+        program_spans=[("serve.batch", 0.5, 1.5, {"n": 1}),
+                       ("serve.batch", 2.0, 2.5, {"n": 2, "wait_us": 30}),
+                       ("serve.device", 2.1, 2.2, {}),
+                       ("serve.batch", 4.9, 5.2, {"n": 3}),
+                       ("serve.batch", 5.0, 5.1, {"n": 4})])
+    # a span belongs to the window it starts in; the harness's are apart
+    assert s.program_spans_named("serve.batch") == [
+        (2.0, 2.5, {"n": 2, "wait_us": 30}), (4.9, 5.2, {"n": 3})]
+    assert s.program_spans_named("serve.device") == [(2.1, 2.2, {})]
+    assert s.idle_gaps() == [["none", 3.0]]
 
 
 def _fixture_ops():

@@ -14,6 +14,11 @@ per-layer metrics read.
 * The top operations are summed by name.
 * Each idle gap of the first device is named by the harness span (other
   than ``window``) that covers most of it on the host, or ``none``.
+* The program's own spans (the names in ``repro.tracing.SPANS``) are kept
+  apart from the harness's, each with its arguments: the keyword stats
+  its ``TraceAnnotation`` recorded on the host event itself, which
+  ``ProfileData`` shows (unlike an operation's ``tf_op``, a stat of the
+  event's metadata, which ``chipbench.scopes`` reads from the file).
 """
 from __future__ import annotations
 
@@ -82,6 +87,8 @@ class Summary:
     modules: list                        # per device: [(name, start, end)]
     spans: list                          # host: [(name, start, end)]
     clock_shift_s: float = 0.0           # added to the device's times
+    # the program's host spans: [(name, start, end, {argument: value})]
+    program_spans: list = dataclasses.field(default_factory=list)
 
     @property
     def window_s(self) -> float:
@@ -116,6 +123,13 @@ class Summary:
     def module_time(self, match) -> tuple[float, int]:
         """As :meth:`op_time`, for whole programs (XLA modules)."""
         return self._time(self.modules, match)
+
+    def program_spans_named(self, name: str) -> list:
+        """``(start, end, arguments)`` of the program's spans named
+        ``name`` that start inside the window."""
+        lo, hi = self.window
+        return [(s, e, args) for n, s, e, args in self.program_spans
+                if n == name and lo <= s < hi]
 
     def top_ops(self, n: int = 10) -> list:
         """Device seconds by operation, most first; an operation is named
@@ -161,6 +175,8 @@ def _events(line) -> list:
 
 def reduce(path: Path, n_devices: int = 1) -> Summary:
     from jax.profiler import ProfileData
+
+    from repro.tracing import SPANS
     pd = ProfileData.from_file(str(path))
     devices = sorted((p for p in pd.planes
                       if p.name.startswith(DEVICE_PREFIX)),
@@ -176,6 +192,11 @@ def reduce(path: Path, n_devices: int = 1) -> Summary:
     host = [ev for plane in pd.planes if plane.name.startswith("/host:")
             for line in plane.lines for ev in _events(line)]
     spans = [ev for ev in host if ev[0] in SPAN_NAMES]
+    program = [(e.name, e.start_ns * 1e-9,
+                (e.start_ns + e.duration_ns) * 1e-9, dict(e.stats))
+               for plane in pd.planes if plane.name.startswith("/host:")
+               for line in plane.lines for e in line.events
+               if e.name in SPANS]
     asked = sorted(s for name, s, _ in host if name == EXECUTE)
     ran = sorted(s for _, s, _ in modules[0])
     shift = 0.0
@@ -190,4 +211,4 @@ def reduce(path: Path, n_devices: int = 1) -> Summary:
         every = [t for dev in ops for _, s, e in dev for t in (s, e)]
         window = (min(every), max(every))
     return Summary(window=window, ops=ops, modules=modules, spans=spans,
-                   clock_shift_s=shift)
+                   clock_shift_s=shift, program_spans=program)
