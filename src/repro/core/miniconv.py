@@ -95,6 +95,10 @@ class MiniConvSpec:
     budget: ShaderBudget = PI_ZERO_BUDGET
 
     @property
+    def c_in(self) -> int:
+        return self.layers[0].c_in
+
+    @property
     def k_out(self) -> int:
         return self.layers[-1].c_out
 

@@ -1,4 +1,4 @@
-"""repro.deploy — ONE declarative deployment API: MiniConvSpec -> served policy.
+"""repro.deploy — ONE declarative deployment API: encoder spec -> served policy.
 
 The paper's artifact is a deployment *pipeline*: a
 :class:`~repro.core.miniconv.MiniConvSpec` is compiled into an ordered
@@ -22,6 +22,12 @@ pipeline ONE object:
   :class:`SplitModel`, the RL-facing :class:`~repro.rl.networks.Encoder`,
   the codec, and factories for a ready ``EdgeClient`` /
   ``BatchingPolicyServer`` pair.
+
+Two encoder families deploy: MiniConv (:class:`~repro.core.miniconv.
+MiniConvSpec`), split at its feature map, on any backend; and IMPALA-CNN
+(:class:`~repro.core.impala.ImpalaSpec`), server-only on ``xla``: the
+edge half is the identity, the codec carries the frame itself, and the
+server runs torso and head.
 
 Every entry point — training (``repro.rl.train``), serving, and the
 benchmarks — constructs the pipeline through ``Deployment.build``; the
@@ -53,7 +59,9 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.core import impala
 from repro.core.backends import ExecutionBackend, backend_names, get_backend
+from repro.core.impala import ImpalaSpec
 from repro.core.miniconv import (_ACTS, LayerSpec, MiniConvSpec,
                                  ShaderBudget, miniconv_apply, standard_spec)
 from repro.core.passplan import HeadPlan, PassPlan, build_pass_plan
@@ -70,9 +78,11 @@ from repro.serving.server import BatchingPolicyServer
 from repro.tracing import scope
 
 # version 2 added the optional ``tuning`` block (a frozen TunedPlan);
-# version-1 manifests load unchanged with ``tuning=None``.
-CONFIG_VERSION = 2
-_READABLE_VERSIONS = (1, 2)
+# version-1 manifests load unchanged with ``tuning=None``.  Version 3 tags
+# the spec with its encoder ``family``; older manifests are MiniConv.
+CONFIG_VERSION = 3
+_READABLE_VERSIONS = (1, 2, 3)
+_FAMILIES = ("miniconv", "impala")
 
 
 # ---------------------------------------------------------------------------
@@ -85,7 +95,9 @@ class DeploymentConfig:
 
     Fields
     ------
-    spec            : the MiniConv encoder architecture (budget-checked).
+    spec            : the encoder architecture: a budget-checked
+                      :class:`MiniConvSpec`, or an :class:`ImpalaSpec`
+                      (server-only, ``xla`` backend only).
     in_h, in_w      : the concrete input size the edge device sees.
     backend         : execution-backend name (``repro.core.backends``):
                       ``xla`` | ``reference`` | ``grouped`` | ``fused`` |
@@ -124,7 +136,7 @@ class DeploymentConfig:
                       the tuned kernels.
     """
 
-    spec: MiniConvSpec
+    spec: MiniConvSpec | ImpalaSpec
     in_h: int
     in_w: int
     backend: str = "fused"
@@ -161,16 +173,39 @@ class DeploymentConfig:
     def from_encoder_name(cls, name: str, *, c_in: int, h: int = 84,
                           w: Optional[int] = None,
                           **overrides) -> "DeploymentConfig":
-        """``miniconv<K>`` (the ``rl.networks.make_encoder`` names)."""
+        """``miniconv<K>`` (the ``rl.networks.make_encoder`` names), or
+        ``impala``: IMPALA-CNN at Procgen's widths, server-only on
+        ``xla`` with its 256-wide head unless ``overrides`` say else."""
+        if name == "impala":
+            overrides = {"backend": "xla", "head_dim": 256, **overrides}
+            return cls(spec=ImpalaSpec(c_in=c_in), in_h=h,
+                       in_w=h if w is None else w, **overrides)
         if not name.startswith("miniconv"):
-            raise ValueError(f"not a MiniConv deployment: {name!r} "
-                             f"(full_cnn has no split pipeline)")
+            raise ValueError(f"no deployment for encoder {name!r}: one of "
+                             f"miniconv<K>, impala (full_cnn has no split "
+                             f"pipeline)")
         k = int(name.replace("miniconv", ""))
         return cls.standard(k=k, c_in=c_in, h=h, w=w, **overrides)
+
+    @property
+    def family(self) -> str:
+        """The encoder family: ``miniconv`` or ``impala``."""
+        return "impala" if isinstance(self.spec, ImpalaSpec) else "miniconv"
 
     # ---- validation --------------------------------------------------------
     def validate(self) -> None:
         get_backend(self.backend)          # raises listing registered names
+        if self.family == "impala":
+            if self.backend != "xla" or self.tuning is not None:
+                raise ValueError(
+                    f"the impala family runs on the xla backend only, "
+                    f"untuned; got backend {self.backend!r}"
+                    f"{' with a tuning block' if self.tuning else ''}")
+            if self.head_placement != "server":
+                raise ValueError(
+                    f"the impala family deploys server-only: "
+                    f"head_placement must be 'server', got "
+                    f"{self.head_placement!r}")
         if self.codec not in CODECS:
             raise ValueError(f"unknown codec {self.codec!r}; registered: "
                              f"{', '.join(CODECS)}")
@@ -208,10 +243,15 @@ class DeploymentConfig:
     def to_dict(self) -> dict:
         """JSON-safe manifest; inverse of :meth:`from_dict`."""
         d = dataclasses.asdict(self)
-        d["spec"] = {
-            "layers": [dataclasses.asdict(l) for l in self.spec.layers],
-            "budget": dataclasses.asdict(self.spec.budget),
-        }
+        if self.family == "impala":
+            d["spec"] = {"family": "impala",
+                         **dataclasses.asdict(self.spec)}
+        else:
+            d["spec"] = {
+                "family": "miniconv",
+                "layers": [dataclasses.asdict(l) for l in self.spec.layers],
+                "budget": dataclasses.asdict(self.spec.budget),
+            }
         d["tuning"] = None if self.tuning is None else self.tuning.to_dict()
         d["version"] = CONFIG_VERSION
         return d
@@ -221,10 +261,17 @@ class DeploymentConfig:
         d = dict(d)
         check_version("DeploymentConfig manifest",
                       d.pop("version", CONFIG_VERSION), _READABLE_VERSIONS)
-        s = d.pop("spec")
-        spec = MiniConvSpec(
-            layers=tuple(LayerSpec(**l) for l in s["layers"]),
-            budget=ShaderBudget(**s.get("budget", {})))
+        s = dict(d.pop("spec"))
+        family = s.pop("family", "miniconv")
+        if family == "impala":
+            spec = ImpalaSpec(**s)
+        elif family == "miniconv":
+            spec = MiniConvSpec(
+                layers=tuple(LayerSpec(**l) for l in s["layers"]),
+                budget=ShaderBudget(**s.get("budget", {})))
+        else:
+            raise ValueError(f"unknown encoder family {family!r}; one of "
+                             f"{', '.join(_FAMILIES)}")
         # pre-tuning (version-1) manifests default cleanly to tuning=None;
         # __post_init__ revives a serialised TunedPlan dict
         return cls(spec=spec, **d)
@@ -252,8 +299,8 @@ class Deployment:
 
     config: DeploymentConfig
     backend: ExecutionBackend
-    plan: PassPlan
-    head_plan: HeadPlan
+    plan: Optional[PassPlan]            # None for the impala family
+    head_plan: Optional[HeadPlan]       # None for the impala family
     codec: WireCodec
     split: SplitModel
     encoder: Encoder
@@ -280,8 +327,13 @@ class Deployment:
         streamed launch (the decision is recorded in ``build_log``).
         Build still fails, with the computed ``max_safe_batch`` and the
         tuner's suggestion, when even a single frame exceeds the budget.
+
+        An :class:`ImpalaSpec` builds the server-only placement
+        (:meth:`_build_impala`).
         """
         config.validate()
+        if config.family == "impala":
+            return cls._build_impala(config)
         backend = get_backend(config.backend)
         tile_h = config.tile_h
         tuning = config.tuning
@@ -394,6 +446,39 @@ class Deployment:
                    stream_chunk=stream_chunk, compiled=compiled,
                    build_log=tuple(log))
 
+    @classmethod
+    def _build_impala(cls, config: DeploymentConfig) -> "Deployment":
+        """The server-only placement of an IMPALA-CNN: the edge half is the
+        identity on the frame, the codec carries the frame, and the server
+        half (and ``encoder.apply``) is torso plus head on ``xla``."""
+        spec, act = config.spec, _ACTS[config.head_act]
+
+        def edge_apply(edge_params, obs):
+            return obs
+
+        def server_apply(server_params, frames):
+            # a stacked micro-batch of one-frame payloads is (B, 1, H, W, C)
+            frames = frames.reshape((-1,) + frames.shape[-3:])
+            return impala.apply(spec, server_params, frames, act)
+
+        def init(key):
+            return {"edge": {},
+                    "server": impala.init(key, spec, h=config.in_h,
+                                          w=config.in_w,
+                                          head_dim=config.head_dim)}
+
+        def encoder_apply(params, obs):
+            return server_apply(params["server"], obs)
+
+        split = SplitModel(edge_apply=edge_apply, server_apply=server_apply,
+                           codec=get_codec(config.codec),
+                           quantize_in_train=config.quantize_in_train)
+        return cls(config=config, backend=get_backend(config.backend),
+                   plan=None, head_plan=None, codec=split.codec, split=split,
+                   encoder=Encoder(name="impala", init=init,
+                                   apply=encoder_apply),
+                   max_safe_batch=config.max_batch, tile_h=config.tile_h)
+
     # ---- over-budget diagnostics ------------------------------------------
     @staticmethod
     def _suggestion(config) -> str:
@@ -434,29 +519,41 @@ class Deployment:
     # ---- parameters --------------------------------------------------------
     def init(self, key):
         """{"edge": conv params, "server": {"proj": dense}} — the dict split
-        IS the deployment split."""
+        IS the deployment split.  The impala family's edge is empty: its
+        torso and head are all ``"server"``."""
         return self.encoder.init(key)
 
     # ---- accounting --------------------------------------------------------
     @property
-    def spec(self) -> MiniConvSpec:
+    def spec(self) -> MiniConvSpec | ImpalaSpec:
+        """The encoder's spec, of either family (``config.family``)."""
         return self.config.spec
 
     @property
     def wire_bytes(self) -> int:
         """Exact bytes of one request's payload on the link."""
-        return self.split.wire_bytes()
+        return self.wire_bytes_batch(1)
 
     def wire_bytes_batch(self, batch: Optional[int] = None) -> int:
-        return self.split.wire_bytes(
-            batch=self.config.max_batch if batch is None else batch)
+        """Exact link bytes of ``batch`` requests (default ``max_batch``),
+        each with its codec header: MiniConv sends its feature map, the
+        server-only impala family its frame."""
+        b = self.config.max_batch if batch is None else batch
+        if self.plan is None:
+            c = self.config
+            return self.split.wire_bytes((c.in_h, c.in_w, c.spec.c_in),
+                                         batch=b)
+        return self.split.wire_bytes(batch=b)
 
     @property
     def frame_bytes(self) -> int:
-        """Bytes of the raw observation upload the server-only baseline
-        transmits (RGBA-packed: 4 channels per texture)."""
-        c = self.spec.layers[0].c_in
-        return self.config.in_h * self.config.in_w * (-(-c // 4) * 4)
+        """Bytes of the raw observation upload of a server-only deployment:
+        the MiniConv baseline's RGBA-packed frame (4 channels per texture),
+        or the impala family's ``in_h·in_w·c_in`` frame as it is."""
+        c = self.spec.c_in
+        if self.config.family == "miniconv":
+            c = -(-c // 4) * 4
+        return self.config.in_h * self.config.in_w * c
 
     # ---- served pipeline ---------------------------------------------------
     @staticmethod
@@ -658,8 +755,7 @@ def _verify_roundtrip(cfg: DeploymentConfig, *, seed: int = 0) -> None:
     params = dep.init(key)
     params2 = dep2.init(key)
     obs = jax.random.uniform(jax.random.PRNGKey(seed + 1),
-                             (1, cfg.in_h, cfg.in_w,
-                              cfg.spec.layers[0].c_in))
+                             (1, cfg.in_h, cfg.in_w, cfg.spec.c_in))
     np.testing.assert_array_equal(dep.encoder.apply(params, obs),
                                   dep2.encoder.apply(params2, obs))
     p1 = dep.split.edge_step(params["edge"], obs)
@@ -679,7 +775,7 @@ def _real_fleet_check(cfg: DeploymentConfig, *, n_requests: int = 8,
     client, server = dep.serving_pair(params)
     obs = jax.random.uniform(
         jax.random.PRNGKey(seed + 1),
-        (n_requests, cfg.in_h, cfg.in_w, cfg.spec.layers[0].c_in))
+        (n_requests, cfg.in_h, cfg.in_w, cfg.spec.c_in))
     payloads = [client.encode_fn(obs[i:i + 1]) for i in range(n_requests)]
     want = [np.asarray(server.serve([p])[0]) for p in payloads]
     fleet = dep.fleet(params)

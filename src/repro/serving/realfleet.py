@@ -467,7 +467,7 @@ def _build_worker(manifest: dict, params, max_batch: int,
     if precompile:
         edge = dep.split.edge_step(
             Deployment._split_params(params)["edge"],
-            np.zeros((1, cfg.in_h, cfg.in_w, cfg.spec.layers[0].c_in),
+            np.zeros((1, cfg.in_h, cfg.in_w, cfg.spec.c_in),
                      np.float32))
         # per-request payloads keep their leading 1-axis (stacking matches
         # wire.stack_payloads: the micro-batch is (B, 1, ...))

@@ -33,6 +33,12 @@ SCOPES = {
     "miniconv.kernel": "the fused encoder pallas_call",
     "miniconv.out": "features and projection sliced out of the kernel's "
                     "padded outputs",
+    "impala.encode": "the whole IMPALA-CNN step: torso and head (the "
+                     "encode step, and the server half after wire.decode)",
+    "impala.conv": "each IMPALA stack's entry conv",
+    "impala.pool": "each IMPALA stack's 3x3 stride-2 max-pool",
+    "impala.res": "each IMPALA stack's residual blocks",
+    "impala.head": "the IMPALA head: flatten, ReLU, dense and activation",
     "wire.decode": "the wire codec's decode of a payload on the server",
     "split.project": "the server half's dense projection and activation",
 }

@@ -185,9 +185,12 @@ def test_worker_server_spans_name_the_requests_they_served(tmp_path):
         for t in threads:
             t.join(10.0)
     finally:
-        jax.profiler.stop_trace()
+        # the serve loop exits only after its last ``serve.batch`` has
+        # closed, which the clients' answers precede
         fc.shutdown()
         ws.join(5.0)
+        jax.profiler.stop_trace()
+    assert not ws.is_alive()
     assert ws.batch_sizes == [1, 3]
     events = _host_events(tmp_path)
     batches = [(st, s, e) for name, st, s, e in events
