@@ -13,11 +13,14 @@ accounting in the roofline analysis.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import math
 from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax import lax
 
 from repro.tracing import scope
 
@@ -161,6 +164,67 @@ def unstack_payload(payload: Payload) -> list[Payload]:
     """Inverse of :func:`stack_payloads`."""
     n = next(iter(payload.values())).shape[0]
     return [{k: v[i] for k, v in payload.items()} for i in range(n)]
+
+
+@dataclasses.dataclass(frozen=True)
+class RowLayout:
+    """A codec's payload as one row of bytes per request.
+
+    Derived once from an example per-request payload (:meth:`of`): the
+    keys in sorted order (as ``realfleet.pack_payload`` orders them), each
+    leaf's dtype and per-request shape, and its byte offset in the row.
+    :meth:`pack` lays a stacked micro-batch out on the host as one
+    ``(B, row_bytes)`` uint8 block, so it crosses to the device as one
+    buffer; :meth:`unpack`, traced into the server half's program, slices
+    each leaf's bytes back out and bitcasts them to its dtype.  Only the
+    bytes' layout changes: every leaf comes back bitwise.
+    """
+
+    keys: tuple[str, ...]
+    dtypes: tuple[np.dtype, ...]
+    shapes: tuple[tuple[int, ...], ...]
+    offsets: tuple[int, ...]
+    row_bytes: int
+
+    @classmethod
+    def of(cls, payload) -> "RowLayout":
+        """The layout of ``payload``, one request's leaves (arrays, or
+        anything with a ``shape`` and a ``dtype``)."""
+        keys = tuple(sorted(payload))
+        dtypes = tuple(np.dtype(payload[k].dtype) for k in keys)
+        shapes = tuple(tuple(payload[k].shape) for k in keys)
+        sizes = [math.prod(s) * d.itemsize for s, d in zip(shapes, dtypes)]
+        return cls(keys=keys, dtypes=dtypes, shapes=shapes,
+                   offsets=tuple(itertools.accumulate(sizes[:-1],
+                                                      initial=0)),
+                   row_bytes=sum(sizes))
+
+    def pack(self, stacked) -> np.ndarray:
+        """Host half: a stacked payload (leading batch axis on every
+        leaf) -> one ``(B, row_bytes)`` uint8 array, one allocation."""
+        leaves = []
+        for key, dtype, shape in zip(self.keys, self.dtypes, self.shapes):
+            leaf = np.ascontiguousarray(stacked[key], dtype=dtype)
+            if leaf.shape[1:] != shape:
+                raise ValueError(f"payload leaf {key!r} has per-request "
+                                 f"shape {leaf.shape[1:]}, the layout "
+                                 f"{shape}")
+            leaves.append(leaf.reshape(leaf.shape[0], -1).view(np.uint8))
+        return np.concatenate(leaves, axis=1)
+
+    def unpack(self, rows) -> Payload:
+        """Traced half: ``(B, row_bytes)`` uint8 -> the stacked payload."""
+        b = rows.shape[0]
+        out = {}
+        for key, dtype, shape, off in zip(self.keys, self.dtypes,
+                                          self.shapes, self.offsets):
+            n = math.prod(shape) * dtype.itemsize
+            seg = rows[:, off:off + n]
+            if dtype.itemsize > 1:
+                seg = seg.reshape(b, n // dtype.itemsize, dtype.itemsize)
+            out[key] = lax.bitcast_convert_type(seg, dtype).reshape(
+                (b,) + shape)
+        return out
 
 
 def frame_bytes_rgba(x_size: int) -> int:

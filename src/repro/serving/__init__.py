@@ -88,7 +88,11 @@ The batched request path end-to-end: each client encodes ONE frame
 with ``repro.core.wire.stack_payloads`` (per-request quantisation headers
 survive stacking), and the server decodes + projects the whole
 micro-batch in one call (``Deployment.server_batch_fn`` /
-``SplitModel.server_step_batch``).
+``SplitModel.server_step_batch``).  A ``realfleet`` worker hands that call
+the micro-batch as ONE host array: ``repro.core.wire.RowLayout`` packs
+each request's leaves into one row of bytes, the block crosses to the
+device as one buffer, and the jitted program unpacks it before the
+server half runs.
 """
 from repro.serving.netsim import (LINK_KINDS, LinkTrace, LossyLink,
                                   MarkovLink, ShapedLink,

@@ -15,7 +15,8 @@ wall-clock measurements (the DistrEdge-style sim-to-real calibration in
   of a spawned worker process (:func:`_worker_main`); either way the
   jitted server half is rebuilt from the deployment manifest
   (:func:`_build_worker` — compiled functions cannot cross a process
-  boundary).
+  boundary), and each micro-batch reaches it as one uint8 block
+  (``repro.core.wire.RowLayout``) that the server half's program unpacks.
 * :class:`FleetClient` — the front door: one socket per worker, requests
   routed by the SAME registered policies the simulator uses
   (``repro.serving.fleet.ROUTERS``), with per-request timeouts and
@@ -251,7 +252,11 @@ class WorkerServer:
     ``serve_batch_fn`` maps a stacked payload dict (leading batch axis on
     every tensor, exactly ``repro.core.wire.stack_payloads``) to stacked
     actions — the same callable :class:`~repro.serving.server.
-    BatchingPolicyServer` wraps in-process.
+    BatchingPolicyServer` wraps in-process.  A fleet worker's
+    (:func:`_build_worker`) packs the dict into one uint8 block, so a
+    micro-batch crosses to the device as one buffer; the ``serve.device``
+    span records that count as ``buffers`` (the callable's ``buffers``
+    attribute, else one per payload leaf).
 
     Admission is CONTINUOUS batching: the serve loop blocks for the first
     request, then admits everything already queued (up to ``max_batch``)
@@ -419,7 +424,8 @@ class WorkerServer:
             stacked = {k: np.stack([r.payload[k] for r in batch])
                        for k in batch[0].payload}
         try:
-            with span("serve.device"):
+            with span("serve.device", buffers=getattr(
+                    self.serve_batch_fn, "buffers", len(stacked))):
                 out = self.serve_batch_fn(stacked)
             if isinstance(out, jax.Array):
                 self.devices |= out.devices()
@@ -442,6 +448,23 @@ class WorkerServer:
         self.batch_sizes.append(len(batch))
 
 
+class _BlockServe:
+    """A worker's ``serve_batch_fn``: the stacked payload packed into one
+    host block (:meth:`~repro.core.wire.RowLayout.pack`) and handed to
+    ``fn``, the jitted program that places it on the replica's device as
+    ONE buffer, unpacks it and runs the deployment's ``server_batch_fn``.
+    ``buffers`` is the number of host arrays each micro-batch copies to
+    the device (the ``serve.device`` span's)."""
+
+    buffers = 1
+
+    def __init__(self, layout, fn: Callable):
+        self.layout, self.fn = layout, fn
+
+    def __call__(self, stacked):
+        return self.fn(self.layout.pack(stacked))
+
+
 def _build_worker(manifest: dict, params, max_batch: int,
                   precompile: bool = True, shaping: Optional[dict] = None,
                   device=None) -> WorkerServer:
@@ -450,30 +473,38 @@ def _build_worker(manifest: dict, params, max_batch: int,
     Rebuilds the jitted server half from the manifest (jitted callables
     cannot cross a process boundary; the manifest + numpy parameter
     pytree can) and optionally pre-compiles every admissible batch shape
-    so the first live micro-batches are not compile-skewed.  With a
-    ``device``, the parameters and every micro-batch are placed on it, so
-    the server half runs there.
+    so the first live micro-batches are not compile-skewed.  A
+    micro-batch reaches the device as one uint8 block of one row per
+    request, laid out by the codec's :class:`~repro.core.wire.RowLayout`
+    (derived from the edge half's payload shape), and the one jitted
+    program ``fn`` unpacks it and runs ``Deployment.server_batch_fn`` on
+    it.  With a ``device``, the parameters and every block are placed on
+    it, so the server half runs there.
     """
+    from repro.core.wire import RowLayout
     from repro.deploy import Deployment, DeploymentConfig  # lazy: cycle
     cfg = DeploymentConfig.from_dict(manifest)
     dep = Deployment.build(cfg)
-    if device is None:
-        serve = dep.server_batch_fn(params)
-    else:
-        on_device = dep.server_batch_fn(jax.device_put(params, device))
+    # per-request payloads keep their leading 1-axis (stacking matches
+    # wire.stack_payloads: the micro-batch is (B, 1, ...))
+    example = jax.eval_shape(
+        dep.split.edge_step, Deployment._split_params(params)["edge"],
+        jax.ShapeDtypeStruct((1, cfg.in_h, cfg.in_w, cfg.spec.c_in),
+                             np.float32))
+    layout = RowLayout.of(example)
+    batch_fn = dep.server_batch_fn(
+        params if device is None else jax.device_put(params, device))
 
-        def serve(stacked):
-            return on_device(jax.device_put(stacked, device))
+    def fn(rows):
+        return batch_fn(layout.unpack(rows))
+    # the numpy block goes straight to the jitted call, which copies it to
+    # the device in its own dispatch: cheaper than a jax.device_put first
+    place = {} if device is None else {
+        "in_shardings": jax.sharding.SingleDeviceSharding(device)}
+    serve = _BlockServe(layout, jax.jit(fn, **place))
     if precompile:
-        edge = dep.split.edge_step(
-            Deployment._split_params(params)["edge"],
-            np.zeros((1, cfg.in_h, cfg.in_w, cfg.spec.c_in),
-                     np.float32))
-        # per-request payloads keep their leading 1-axis (stacking matches
-        # wire.stack_payloads: the micro-batch is (B, 1, ...))
-        example = {k: np.asarray(v) for k, v in edge.items()}
         for b in range(1, max_batch + 1):
-            np.asarray(serve({k: np.stack([v] * b)
+            np.asarray(serve({k: np.zeros((b,) + v.shape, v.dtype)
                               for k, v in example.items()}))
     shaper = (ShapingConfig.from_dict(shaping).bucket()
               if shaping is not None else None)

@@ -52,7 +52,8 @@ SPANS = {
                    "longest queue wait)",
     "serve.stack": "the batch's payloads stacked on the host",
     "serve.device": "the server half's call (dispatch, and the copy to "
-                    "the device)",
+                    "the device; buffers: the host arrays copied for the "
+                    "micro-batch, 1 for a fleet worker's packed block)",
     "serve.fetch": "the actions copied back to the host",
     "serve.send": "the answers framed and sent",
 }

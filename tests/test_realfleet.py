@@ -301,15 +301,33 @@ def test_shaping_config_roundtrip_and_bucket():
 
 
 def test_worker_front_door_shapes_ingress():
-    """A shaped WorkerServer answers correctly AND measurably sleeps:
-    requests beyond the burst pay the token-bucket wait before they are
-    admitted to the batching queue."""
+    """A shaped WorkerServer answers correctly AND spaces its admissions:
+    past the burst, each request reaches the batching queue at least one
+    transmission time (its bytes at the shaped rate) after the one
+    before.  The GCRA bucket refills while a closed-loop client waits for
+    its answer, so the sleeps alone need not add up to the transmission
+    times; the spacing of the admissions does."""
     from repro.serving.realfleet import ShapingConfig
     body = pack_payload(_payload(1, n=256))   # ~1 kB on the wire
-    # tiny burst, 1 Mb/s: every request after the first must wait
-    shaper = ShapingConfig(rate_mbps=1.0, burst_bytes=len(body)).bucket()
-    ws = WorkerServer(lambda s: s["data"] * 2.0, max_batch=4,
-                      shaper=shaper)
+    rate_bps = 0.125e6
+    tx_s = len(body) * 8 / rate_bps           # ~68 ms a request
+    # a request is stamped (``_Request.arrived``) after its sleep and its
+    # payload's unpack: a stamp may run late by the sleep's wake-up on a
+    # loaded host and a thread switch (Python's switch interval is 5 ms),
+    # which this tolerance covers, taken off the spacing of the stamp that
+    # follows.  An unshaped worker spaces them by a round trip, ~1 ms.
+    tol_s = 0.25 * tx_s
+    # tiny burst, 0.125 Mb/s: every request after the first must wait
+    shaper = ShapingConfig(rate_mbps=rate_bps / 1e6,
+                           burst_bytes=len(body)).bucket()
+    arrived: list[float] = []
+
+    class Recording(WorkerServer):
+        def _serve(self, batch):
+            arrived.extend(r.arrived for r in batch)
+            super()._serve(batch)
+
+    ws = Recording(lambda s: s["data"] * 2.0, max_batch=4, shaper=shaper)
     addr = ws.start()
     fc = FleetClient([addr], timeout_s=10.0)
     t0 = time.monotonic()
@@ -317,11 +335,11 @@ def test_worker_front_door_shapes_ingress():
         np.testing.assert_array_equal(fc.request(_payload(1, n=256)),
                                       _payload(2, n=256)["data"])
     elapsed = time.monotonic() - t0
-    expected = 3 * len(body) * 8 / 1e6        # 3 post-burst waits
-    assert ws.shaped_sleep_s >= 0.5 * expected
-    assert elapsed >= 0.5 * expected
     fc.shutdown()
     ws.join(5.0)
+    assert len(arrived) == 4
+    assert np.all(np.diff(arrived) >= tx_s - tol_s), np.diff(arrived)
+    assert elapsed >= 3 * tx_s                # 3 post-burst transmissions
     # unshaped control: no accumulated sleep
     ws2 = WorkerServer(lambda s: s["data"] * 2.0, max_batch=4)
     addr2 = ws2.start()

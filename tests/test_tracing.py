@@ -211,3 +211,31 @@ def test_worker_server_spans_name_the_requests_they_served(tmp_path):
     assert {i for i, _ in requests} == {0, 1, 2, 3}
     assert {c for _, c in requests} == {0, 1, 2, 3}
     assert any(name == "serve.admit" for name, _, _, _ in events)
+
+
+def test_serve_device_span_counts_the_buffers(tmp_path):
+    """``serve.device`` carries ``buffers``, the host arrays a micro-batch
+    copies to the device: 1 for a fleet worker, which packs the uint8
+    codec's three leaves into one block, and one per leaf for a plain
+    ``serve_batch_fn`` handed the stacked dict."""
+    from repro.serving.realfleet import _build_worker
+    dep = _deployment("xla")
+    params = jax.tree.map(np.asarray, dep.init(jax.random.PRNGKey(0)))
+    payload = {k: np.asarray(v) for k, v in dep.split.edge_step(
+        params["edge"], jnp.zeros((1, 16, 16, 9))).items()}
+    assert len(payload) == 3
+    block = _build_worker(dep.config.to_dict(), params, 2)
+    per_leaf = WorkerServer(dep.server_batch_fn(params), max_batch=2)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for ws in (block, per_leaf):
+            fc = FleetClient([ws.start()], timeout_s=10.0, retries=0)
+            fc.request(payload)
+            fc.shutdown()
+            ws.join(5.0)
+    finally:
+        jax.profiler.stop_trace()
+    buffers = [st["buffers"] for name, st, _, _ in
+               sorted(_host_events(tmp_path), key=lambda e: e[2])
+               if name == "serve.device"]
+    assert buffers == [1, 3]
